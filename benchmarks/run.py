@@ -34,6 +34,9 @@ def main() -> None:
                     help="comma-separated module subset")
     args = ap.parse_args()
     mods = args.only.split(",") if args.only else MODULES
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

@@ -31,9 +31,11 @@ External ids are pre-balanced across the 2 shards so every batch owns
 exactly B/S lanes per shard (the steady-state of hash routing at scale);
 the bench then isolates the scan-width mechanism instead of hash luck.
 Timing is interleaved min-of-repeats (``update_bench`` discipline: box
-noise on this 1-core-class CI machine swings >10%, so every path samples
-every round) and runs in a subprocess so the forced 2-device host
-platform cannot leak into the caller's JAX runtime.
+noise on a 1-core-class CI machine swings >10%, so every path samples
+every round).  On an accelerator the bench runs in the calling process on
+the first two of ``jax.devices()`` (and refuses on a one-device host); on
+CPU it runs in a child process whose two devices are forced host devices,
+so that flag cannot leak into the caller's JAX runtime.
 
 Results merge into ``BENCH_update.json`` under the ``"shard"`` key.  In
 --smoke mode the gates run on aggregate min-of-repeats only: batched-mode
@@ -55,31 +57,31 @@ from typing import List
 
 from .common import REPO, Row, scale
 
-SCRIPT = textwrap.dedent("""
-    import json, sys, time
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    import warnings; warnings.filterwarnings("ignore")
-    import jax, numpy as np
+def _bench(params: dict, devices) -> dict:
+    """The measurement, in the calling process, on the first two
+    ``devices``."""
+    import time
+
+    import jax
+    import numpy as np
     from repro.core import ANNConfig, clone_state, delete_batch, insert_batch
     from repro.core.distributed import ShardedIndex
 
-    params = json.loads(sys.argv[1])
     S, T, B = 2, params["T"], params["B"]
     repeat = params["repeat"]
     cfg = ANNConfig(dim=params["dim"], n_cap=params["n_cap"], r=params["r"],
                     l_build=params["l"], l_search=params["l"],
                     l_delete=params["l"], k_delete=params["k_delete"],
                     n_copies=2, consolidation_threshold=1e9)
-    mesh = jax.make_mesh((S,), ("shard",))
+    mesh = jax.make_mesh((S,), ("shard",), devices=devices[:S])
     rng = np.random.default_rng(0)
 
     # pre-balanced external ids: every B-lane batch owns B/S per shard
     pool = np.arange(params["n_ids"])
-    class F: n_shards = S
-    own = ShardedIndex.route(F, pool)
+    own = ShardedIndex(cfg, mesh, max_external_id=1).route(pool)
     per = [pool[own == s] for s in range(S)]
     half = B // S
+
     def batch_ids(i):
         return np.concatenate([p[i * half:(i + 1) * half] for p in per])
 
@@ -93,7 +95,9 @@ SCRIPT = textwrap.dedent("""
         new = batch_ids(n_boot + t)
         stream.append(insert_batch(new, data[new]))
 
-    out = {"S": S, "T": T, "B": B, "repeat": repeat, "mode": {}}
+    out = {"S": S, "T": T, "B": B, "repeat": repeat, "mode": {},
+           "device": {"platform": devices[0].platform,
+                      "kind": devices[0].device_kind, "count": S}}
     for sequential in (False, True):
         idxs = {}
         for routing in ("compact", "replicate"):
@@ -110,7 +114,8 @@ SCRIPT = textwrap.dedent("""
             jax.block_until_ready(idx.states.graph.adj)
 
         # semantics parity is a precondition for timing to mean anything
-        run("compact"); run("replicate")
+        run("compact")
+        run("replicate")
         for x, y in zip(jax.tree.leaves(idxs["compact"][0].states),
                         jax.tree.leaves(idxs["replicate"][0].states)):
             assert np.array_equal(np.asarray(x), np.asarray(y)), (
@@ -134,7 +139,18 @@ SCRIPT = textwrap.dedent("""
             "replicate_updates_per_s": n_updates / best["replicate"],
             "compact_updates_per_s": n_updates / best["compact"],
         }
-    print(json.dumps(out))
+    return out
+
+
+# Off an accelerator the two shards are forced host devices, which only a
+# fresh process can configure (XLA reads the flag at backend start-up).
+CPU_CHILD = textwrap.dedent("""
+    import json, os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    import warnings; warnings.filterwarnings("ignore")
+    import jax
+    from benchmarks.shard_bench import _bench
+    print(json.dumps(_bench(json.loads(sys.argv[1]), jax.devices())))
 """)
 
 
@@ -146,22 +162,34 @@ def run_bench(n_cap: int, dim: int, r: int, t_steps: int, b: int,
         # enough balanced ids for bootstrap + stream inserts
         "n_ids": (t_steps + t_steps // 2 + 2) * b,
     }
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.pop("JAX_PLATFORMS", None)
-    out = subprocess.run(
-        [sys.executable, "-c", SCRIPT, json.dumps(params)],
-        env=env, capture_output=True, text=True, timeout=1800,
-    )
-    if out.returncode != 0:
-        raise RuntimeError(f"shard bench subprocess failed:\n"
-                           f"{out.stderr[-3000:]}")
-    report = json.loads(out.stdout.strip().splitlines()[-1])
+    import jax
+
+    if jax.default_backend() != "cpu":
+        # an accelerator belongs to this process: measure here, never in
+        # a child that would have to take the device from us
+        devices = jax.devices()
+        if len(devices) < 2:
+            raise RuntimeError(
+                f"shard_bench needs 2 devices; this host has "
+                f"{len(devices)} {devices[0].platform} device"
+            )
+        report = _bench(params, devices)
+    else:
+        env = dict(os.environ)
+        env["PYTHONPATH"] = os.pathsep.join([REPO, os.path.join(REPO, "src")])
+        out = subprocess.run(
+            [sys.executable, "-c", CPU_CHILD, json.dumps(params)],
+            env=env, capture_output=True, text=True, timeout=1800, cwd=REPO,
+        )
+        if out.returncode != 0:
+            raise RuntimeError(f"shard bench subprocess failed:\n"
+                               f"{out.stderr[-3000:]}")
+        report = json.loads(out.stdout.strip().splitlines()[-1])
     report["note"] = (
         "2-shard chained update_stream, balanced ownership; compact = "
         "owner-packed (S, T, Bc) sub-batches, replicate = full-B masked "
         "lanes; batched mode is where masked lanes pay tile width; min of "
-        "interleaved repeats; CPU host-device numbers"
+        "interleaved repeats; device in report['device']"
     )
     return report
 

@@ -1,16 +1,16 @@
 """Distributed serving: the two-tower retrieval arch composed with the
-paper's streaming index, on a shard_map fan-out over 8 (placeholder)
-devices — candidate embeddings stream in and out while queries run.
+paper's streaming index, on a shard_map fan-out over the devices at hand
+(8 host devices under JAX_PLATFORMS=cpu) — candidate embeddings stream in
+and out while queries run.
 
   retrieval path A: exact fused matmul+top-k (repro.kernels.topk_score)
   retrieval path B: sharded IP-DiskANN graph index (sub-linear search)
 
-    python examples/distributed_serving.py        # device count set inside
+    JAX_PLATFORMS=cpu python examples/distributed_serving.py
+    python examples/distributed_serving.py --shards 4     # on 4 chips
 """
+import argparse
 import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -24,10 +24,16 @@ import numpy as np
 from repro.configs.ann import test_scale
 from repro.core.distributed import ShardedIndex
 from repro.kernels.ops import topk_search
+from repro.launch.mesh import shard_mesh
 from repro.models.recsys import TwoTowerConfig, init_two_tower_params, _mlp
 
 
 def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shards", type=int, default=8)
+    args = ap.parse_args()
+    # the mesh first: on CPU its host devices must precede backend start-up
+    mesh = shard_mesh(args.shards)
     n_items, dim = 4000, 64
     cfg_tt = TwoTowerConfig(name="demo", embed_dim=dim,
                             tower_mlp=(128, 64, 32),
@@ -45,7 +51,7 @@ def main():
     t0 = time.perf_counter()
     dists, ids = topk_search(
         jnp.asarray(user_vec), jnp.asarray(item_embs), k=10, metric="ip",
-        tile_n=512, interpret=True,
+        tile_n=512,
     )
     print(f"exact top-10 (fused kernel): {ids[0][:5].tolist()}... "
           f"in {time.perf_counter()-t0:.2f}s")
@@ -53,7 +59,6 @@ def main():
     # --- path B: sharded streaming graph index ------------------------------
     # external-id semantics end to end: the sharded index rides the same
     # unified apply(state, UpdateBatch) front door as StreamingIndex
-    mesh = jax.make_mesh((8,), ("shard",))
     cfg = test_scale(item_embs.shape[1], n_cap=n_items, metric="ip")
     idx = ShardedIndex(cfg, mesh)
     ext = np.arange(n_items)

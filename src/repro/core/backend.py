@@ -261,6 +261,22 @@ class PallasBackend(JnpBackend):
 
     interpret = None  # None => auto: interpret off-TPU, Mosaic on TPU
 
+    def _refuse_on_mosaic(self, what: str, cfg_field: str):
+        """Kernels that only run in interpret mode fail loudly when the
+        kernels compile with Mosaic, rather than silently running some
+        other engine."""
+        from ..kernels import ops
+
+        interpret = self.interpret
+        if interpret is None:
+            interpret = ops._default_interpret()
+        if not interpret:
+            raise NotImplementedError(
+                f"{what} does not compile with Mosaic yet (it runs in "
+                f"interpret mode only); unset ANNConfig.{cfg_field} or pick "
+                f"another backend on TPU"
+            )
+
     def dists_to_ids(self, state, cfg, q, ids):
         from ..kernels import ops
 
@@ -283,6 +299,11 @@ class PallasBackend(JnpBackend):
         from .types import navigable
         from ..kernels import ops
 
+        self._refuse_on_mosaic(
+            "the fused multi-hop kernel (kernels/beam_hop.py: vector "
+            "gathers, in-kernel sort, an (1, n_cap) VMEM norms block)",
+            "hop_fused",
+        )
         # cheap O(n_cap) elementwise packs of the loop-invariant masks;
         # dwarfed by the O(B * R * D) distance math of the h hops
         nav_words = bitset.pack_bits(navigable(state))
@@ -301,6 +322,11 @@ class PallasBackend(JnpBackend):
     def dists_to_ids_batched_q(self, state, cfg, queries, ids):
         from ..kernels import ops
 
+        self._refuse_on_mosaic(
+            "the int8 gather kernel (kernels/quant_gather.py: single-row "
+            "int8 DMAs are not aligned to the (32, 128) int8 tiling)",
+            "quantized",
+        )
         return ops.gather_distances_batched_q(
             ids, queries, state.quant.codes, state.quant.scale,
             state.quant.qnorms, metric=cfg.metric, interpret=self.interpret,
@@ -312,6 +338,10 @@ class PallasBackend(JnpBackend):
         from .types import navigable
         from ..kernels import ops
 
+        self._refuse_on_mosaic(
+            "the fused int8 multi-hop kernel (kernels/beam_hop.py)",
+            "hop_fused",
+        )
         nav_words = bitset.pack_bits(navigable(state))
         ret_words = bitset.pack_bits(state.active)
         out = ops.beam_hop_q(

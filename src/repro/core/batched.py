@@ -8,8 +8,10 @@ splits each update into a *search phase* and a *write phase*:
             beam engine (core/search_batched.py: one shared hop loop, one
             fused (B, R) gather-distance tile per hop) against the
             pre-batch graph (exactly the paper's relaxed visibility);
-  phase 2 — graph writes (prune + edge insertion) apply serially via scan,
-            reusing the precomputed candidate lists.
+  phase 2 — graph writes apply serially via scan, reusing the precomputed
+            candidate lists.  Each op's edges run as one vmapped batch
+            (``edges.append_edges``); inserts' own RobustPrunes run for the
+            whole batch at once before the scan.
 
 The searches dominate update cost (the paper's Table 3 shows deletion time
 is search-bound), so batching them converts the serial update stream into
@@ -29,8 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .delete import DeleteStats, _next_start, _topc_candidates
-from .edges import append_one, remove_target_rows
+from .delete import DeleteStats, _next_start, _repair_edges, _topc_candidates
+from .edges import append_edges, remove_target_rows
 from .insert import InsertStats
 from .prune import robust_prune
 from .quant import quant_write_rows
@@ -86,32 +88,36 @@ def insert_many_batched(state: GraphState, cfg: ANNConfig, xs: jax.Array,
                                 valid=valid)
     vis_ids, vis_dists, comps = res.visited_ids, res.visited_dists, res.n_comps
 
-    # phase 2: serial link application
+    # phase 2a: every lane's out-neighbours at once.  A lane's candidates
+    # are pre-batch vertices (new slots are inactive during phase 1), whose
+    # vectors and liveness the link phase never changes, so RobustPrune
+    # gives each lane the row it would get inside the serial scan.
+    nouts = jax.vmap(
+        lambda x, vids, vdists, slot: robust_prune(
+            state, cfg, x, vids, vdists, p_id=slot)
+    )(xs_f, vis_ids, vis_dists, slots)
+    nouts = jnp.where(ok[:, None], nouts, INVALID)
+
+    # phase 2b: serial link application — lane b's reverse edges see the
+    # rows earlier lanes wrote.  Masked lanes rewrite what is there (no
+    # lax.cond returning the state)
     def link(st: GraphState, args):
-        slot, x, vids, vdists, ok = args
+        slot, nout, ok = args
+        at = clip_ids(slot, cfg.n_cap)
+        st = st._replace(
+            # a dynamic row update, not a scatter (see ``edges.put_rows``)
+            adj=lax.dynamic_update_index_in_dim(
+                st.adj, jnp.where(ok, nout, st.adj[at]), at, 0),
+            active=st.active.at[at].set(st.active[at] | ok),
+            n_active=st.n_active + ok.astype(jnp.int32),
+            free_top=st.free_top - ok.astype(jnp.int32),
+            start=jnp.where(ok & (st.start < 0), slot, st.start),
+        )
+        return append_edges(st, cfg, nout, slot), None
 
-        def do(st: GraphState):
-            nout = robust_prune(st, cfg, x, vids, vdists, p_id=slot)
-            st = st._replace(
-                adj=st.adj.at[clip_ids(slot, cfg.n_cap)].set(nout),
-                active=st.active.at[clip_ids(slot, cfg.n_cap)].set(True),
-                n_active=st.n_active + 1,
-                free_top=st.free_top - 1,
-                start=jnp.where(st.start < 0, slot, st.start),
-            )
-
-            def rev(i, s):
-                return append_one(s, cfg, nout[i], slot)
-
-            return lax.fori_loop(0, cfg.r, rev, st)
-
-        return lax.cond(ok, do, lambda s: s, st), slot
-
-    state, out_slots = lax.scan(
-        link, state, (slots, xs_f, vis_ids, vis_dists, ok)
-    )
+    state, _ = lax.scan(link, state, (slots, nouts, ok))
     stats = InsertStats(
-        slot=jnp.where(ok, out_slots, INVALID),
+        slot=jnp.where(ok, slots, INVALID),
         n_comps=comps,
         n_hops=jnp.zeros_like(comps),
     )
@@ -136,45 +142,33 @@ def ip_delete_many_batched(state: GraphState, cfg: ANNConfig, ps: jax.Array):
     comps_b = res.n_comps
 
     def repair(st: GraphState, args):
+        # branch-free: a masked lane's edges are all INVALID and its slot
+        # writes rewrite what is there
         p, vis, cands, ok = args
         sp = clip_ids(p, cfg.n_cap)
-
-        def do(st: GraphState):
-            nout_p = st.adj[sp]
-            vis_rows = st.adj[clip_ids(vis, cfg.n_cap)]
-            in_mask = jnp.any(vis_rows == p, axis=1) & (vis >= 0)
-            cz = _topc_candidates(st, cfg, vis, cands, cfg.n_copies)
-            st = st._replace(adj=remove_target_rows(
-                st, cfg, jnp.where(in_mask, vis, INVALID), p))
-
-            def z_body(i, s):
-                def add(sz):
-                    def inner(j, s2):
-                        return append_one(s2, cfg, vis[i], cz[i, j])
-                    return lax.fori_loop(0, cfg.n_copies, inner, sz)
-                return lax.cond(in_mask[i], add, lambda sz: sz, s)
-
-            st = lax.fori_loop(0, vis.shape[0], z_body, st)
-            cw = _topc_candidates(st, cfg, nout_p, cands, cfg.n_copies)
-
-            def w_body(i, s):
-                def inner(j, s2):
-                    return append_one(s2, cfg, cw[i, j], nout_p[i])
-                return lax.fori_loop(0, cfg.n_copies, inner, s)
-
-            st = lax.fori_loop(0, cfg.r, w_body, st)
-            new_start = _next_start(st, cfg, p, nout_p)
-            return st._replace(
-                adj=st.adj.at[sp].set(
-                    jnp.full((cfg.r,), INVALID, jnp.int32)),
-                active=st.active.at[sp].set(False),
-                quarantine=st.quarantine.at[sp].set(True),
-                n_active=st.n_active - 1,
-                n_pending=st.n_pending + 1,
-                start=new_start,
-            )
-
-        return lax.cond(ok, do, lambda s: s, st), None
+        nout_p = jnp.where(ok, st.adj[sp], INVALID)
+        vis_rows = st.adj[clip_ids(vis, cfg.n_cap)]
+        in_mask = jnp.any(vis_rows == p, axis=1) & (vis >= 0) & ok
+        # read p's row before the writes: XLA would otherwise re-read it
+        # from the pre-write adjacency after them, keeping a 256 MiB copy
+        nout_p, adj = lax.optimization_barrier((nout_p, st.adj))
+        st = st._replace(adj=adj)
+        cz = _topc_candidates(st, cfg, vis, cands, cfg.n_copies)
+        st = st._replace(adj=remove_target_rows(
+            st, cfg, jnp.where(in_mask, vis, INVALID), p))
+        cw = _topc_candidates(st, cfg, nout_p, cands, cfg.n_copies)
+        st = append_edges(st, cfg,
+                          *_repair_edges(in_mask, vis, cz, nout_p, cw))
+        new_start = _next_start(st, cfg, p, nout_p)
+        return st._replace(
+            adj=lax.dynamic_update_index_in_dim(
+                st.adj, jnp.where(ok, INVALID, st.adj[sp]), sp, 0),
+            active=st.active.at[sp].set(st.active[sp] & ~ok),
+            quarantine=st.quarantine.at[sp].set(st.quarantine[sp] | ok),
+            n_active=st.n_active - ok.astype(jnp.int32),
+            n_pending=st.n_pending + ok.astype(jnp.int32),
+            start=jnp.where(ok, new_start, st.start),
+        ), None
 
     state, _ = lax.scan(repair, state, (ps, vis_b, cands_b, valid))
     stats = DeleteStats(ok=valid, n_comps=comps_b,

@@ -172,41 +172,61 @@ def _release_tombstones(state: GraphState, cfg: ANNConfig) -> GraphState:
 
 
 @functools.partial(jax.jit, donate_argnums=0)
-def _scatter_shard(graphs: GraphState, row: GraphState, s) -> GraphState:
-    """Write one shard's graph back into the donated stacked pytree.
-
-    ``graphs`` is DONATED: XLA updates the consolidated rows in the
-    existing buffers instead of rebuilding every stacked leaf, so the
-    scatter is O(one shard) in copies.  ``s`` is a traced scalar — one
-    compiled program serves every shard id (no per-shard recompiles)."""
+def _write_row(block: GraphState, row: GraphState, i) -> GraphState:
+    """Write one shard's graph into row ``i`` of a DONATED (G, ...) stack
+    block: XLA updates the row in the existing buffers, so the write is
+    O(one shard) in copies.  ``i`` is a traced scalar — one compiled
+    program serves every row."""
     return jax.tree.map(
         lambda full, new: jax.lax.dynamic_update_index_in_dim(
-            full, new.astype(full.dtype), s, 0
+            full, new.astype(full.dtype), i, 0
         ),
-        graphs, row,
+        block, row,
     )
+
+
+def _blocks(x: jax.Array):
+    """The device blocks of ``x``'s leading axis, in axis order."""
+    shards = sorted(x.addressable_shards,
+                    key=lambda sh: sh.index[0].start or 0)
+    if sum(sh.data.shape[0] for sh in shards) != x.shape[0]:
+        raise ValueError("consolidate_stacked needs the stack's leading "
+                         "axis split over its devices, each row held once")
+    return [sh.data for sh in shards]
 
 
 def consolidate_stacked(graphs: GraphState, cfg: ANNConfig, consolidate_fn,
                         shard_ids) -> GraphState:
     """Run a per-shard consolidation pass over a STACKED ``GraphState``
-    (leading shard axis, as ``ShardedIndex`` carries it).
+    (leading shard axis, as ``ShardedIndex`` carries it: on one device, or
+    split in (G, ...) blocks over a mesh axis).
 
-    For each shard in ``shard_ids``: gather that shard's graph off the
-    stacked pytree, run ``consolidate_fn(graph, cfg)`` (e.g. the fresh
-    policy's host-orchestrated Algorithm 4, or ``light_consolidate`` under
-    ``force``), and scatter the result back with the jitted DONATED
-    ``_scatter_shard`` — O(one shard) in copies, one compiled program for
-    every shard id.  (The pre-rework path rebuilt every stacked leaf with
-    an un-jitted ``.at[s].set`` per consolidated shard.)  The caller's
-    ``graphs`` handle is consumed: use the RETURNED stack, exactly as with
-    the donated update front doors.
+    For each shard in ``shard_ids``: take that shard's graph out of the
+    device block that holds it, run ``consolidate_fn(graph, cfg)`` (e.g.
+    the fresh policy's host-orchestrated Algorithm 4, or
+    ``light_consolidate`` under ``force``) on that device, and write the
+    result back with the jitted DONATED ``_write_row``.  Nothing crosses
+    devices and no device holds another's graph; the blocks are then
+    reassembled under the stack's sharding.  The caller's ``graphs`` handle
+    is consumed: use the RETURNED stack, exactly as with the donated update
+    front doors.
     """
+    leaves, tree = jax.tree.flatten(graphs)
+    layouts = [(x.shape, x.sharding) for x in leaves]
+    blocks = [_blocks(x) for x in leaves]
+    starts = np.cumsum([0] + [b.shape[0] for b in blocks[0]])
     for s in shard_ids:
-        g = jax.tree.map(lambda x: x[s], graphs)
-        g = consolidate_fn(g, cfg)
-        graphs = _scatter_shard(graphs, g, jnp.int32(s))
-    return graphs
+        k = int(np.searchsorted(starts, s, side="right")) - 1
+        block = tree.unflatten([b[k] for b in blocks])
+        i = int(s - starts[k])
+        g = consolidate_fn(jax.tree.map(lambda x: x[i], block), cfg)
+        block = _write_row(block, g, jnp.int32(i))
+        for b, x in zip(blocks, jax.tree.leaves(block)):
+            b[k] = x
+    return tree.unflatten([
+        jax.make_array_from_single_device_arrays(shape, sharding, b)
+        for (shape, sharding), b in zip(layouts, blocks)
+    ])
 
 
 def fresh_consolidate(

@@ -7,7 +7,7 @@ Algorithm 5, TPU form:
      (V, r) gather + compare, no in-neighbour lists maintained.
   3. For each z in N'_in: remove edge z->p, add edges z -> closest-c
      candidates to x_z.  The closest-c selection for *all* visited rows is one
-     (V, k) distance matrix + top-c (vectorised before the serial append loop).
+     (V, k) distance matrix + top-c.
   4. For each w in N_out(p): add edges y -> w for the closest-c candidates y
      to x_w ((r, k) matrix + top-c).
   5. Remove p immediately: slot goes to *quarantine* (not the free stack) so
@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .backend import BIG, resolve_backend
-from .edges import append_one, remove_target_everywhere, remove_target_rows
+from .edges import append_edges, remove_target_everywhere, remove_target_rows
 from .search import greedy_search
 from .types import INVALID, ANNConfig, GraphState, clip_ids
 
@@ -81,30 +81,10 @@ def ip_delete(state: GraphState, cfg: ANNConfig, p: jax.Array):
             )
         )
 
-        def z_body(i, s):
-            do = in_mask[i]
-
-            def add(sz):
-                def inner(j, s2):
-                    return append_one(s2, cfg, vis[i], cz[i, j])
-                return lax.fori_loop(0, cfg.n_copies, inner, sz)
-
-            return lax.cond(do, add, lambda sz: sz, s)
-
-        st = lax.fori_loop(0, vis.shape[0], z_body, st)
-
-        # --- replacement edges into p's out-neighbourhood ------------------
+        # --- replacement edges: z -> cz, then cw -> p's out-neighbours -----
         cw = _topc_candidates(st, cfg, nout_p, cands, cfg.n_copies)  # (r, c)
-
-        def w_body(i, s):
-            w = nout_p[i]
-
-            def inner(j, s2):
-                return append_one(s2, cfg, cw[i, j], w)
-
-            return lax.fori_loop(0, cfg.n_copies, inner, s)
-
-        st = lax.fori_loop(0, cfg.r, w_body, st)
+        st = append_edges(st, cfg,
+                          *_repair_edges(in_mask, vis, cz, nout_p, cw))
 
         # --- remove p (quarantine the slot until Algorithm 6) --------------
         new_start = _next_start(st, cfg, p, nout_p)
@@ -123,6 +103,18 @@ def ip_delete(state: GraphState, cfg: ANNConfig, p: jax.Array):
         )
 
     return lax.cond(valid, do_delete, no_op, state)
+
+
+def _repair_edges(in_mask, vis, cz, nout_p, cw):
+    """Algorithm 5's replacement edges as ``(sources, targets)`` in the
+    order the algorithm adds them: each in-neighbour ``vis[i]`` (where
+    ``in_mask``) to its candidates ``cz[i, :]``, then each candidate
+    ``cw[i, j]`` to the out-neighbour ``nout_p[i]``."""
+    z_src = jnp.where(in_mask[:, None], vis[:, None], INVALID)
+    z_src = jnp.broadcast_to(z_src, cz.shape)
+    w_dst = jnp.broadcast_to(nout_p[:, None], cw.shape)
+    return (jnp.concatenate([z_src.reshape(-1), cw.reshape(-1)]),
+            jnp.concatenate([cz.reshape(-1), w_dst.reshape(-1)]))
 
 
 def _next_start(st: GraphState, cfg: ANNConfig, p, nout_p):
@@ -206,16 +198,7 @@ def local_delete(state: GraphState, cfg: ANNConfig, p: jax.Array):
         # --- reconnect the bounded in-neighbourhood through N_out(p) -------
         cz = _topc_candidates(st, cfg, z_ids, nout_p, cfg.n_copies)
 
-        def z_body(i, s):
-            def add(sz):
-                def inner(j, s2):
-                    return append_one(s2, cfg, z_ids[i], cz[i, j])
-
-                return lax.fori_loop(0, cfg.n_copies, inner, sz)
-
-            return lax.cond(z_ids[i] >= 0, add, lambda sz: sz, s)
-
-        st = lax.fori_loop(0, z_ids.shape[0], z_body, st)
+        st = append_edges(st, cfg, z_ids[:, None], cz)
 
         # --- release the slot directly (no quarantine, no pending debt) ----
         new_start = _next_start(st, cfg, p, nout_p)

@@ -78,7 +78,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .api import (
@@ -270,11 +269,11 @@ class ShardedIndex:
                     comps[None],
                 )
 
-            return shard_map(
+            return jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(axis), P()),       # queries replicated
                 out_specs=(P(axis), P(axis), P(axis), P(axis)),
-                check_rep=False,  # while-loop carries mix varying/invariant axes
+                check_vma=False,  # while-loop carries mix varying/invariant axes
             )(states, queries)
 
         return search
@@ -338,11 +337,11 @@ class ShardedIndex:
                 # S rotations: every sub-batch is back on its home shard
                 return best_i, best_s, best_d, comps[None]
 
-            return shard_map(
+            return jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(axis), P(axis), P(axis)),
                 out_specs=(P(axis), P(axis), P(axis), P(axis)),
-                check_rep=False,
+                check_vma=False,
             )(states, queries, valid)
 
         return search_p
@@ -387,11 +386,11 @@ class ShardedIndex:
                     ress.append(res)
                 return _restack(rows), _restack(ress)
 
-            return shard_map(
+            return jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(axis), P(), P()),
                 out_specs=(P(axis), P(axis)),
-                check_rep=False,
+                check_vma=False,
             )(states, batch, owners)
 
         return update
@@ -428,11 +427,11 @@ class ShardedIndex:
                     ress.append(res)
                 return _restack(rows), _restack(ress)
 
-            return shard_map(
+            return jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(axis), P(axis)),
                 out_specs=(P(axis), P(axis)),
-                check_rep=False,
+                check_vma=False,
             )(states, batch)
 
         return update
@@ -470,11 +469,11 @@ class ShardedIndex:
                     ress.append(res)
                 return _restack(rows), _restack(ress)
 
-            return shard_map(
+            return jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(axis), P(), P()),
                 out_specs=(P(axis), P(axis)),
-                check_rep=False,
+                check_vma=False,
             )(states, ops, owners)
 
         return update_segment
@@ -505,11 +504,11 @@ class ShardedIndex:
                     ress.append(res)
                 return _restack(rows), _restack(ress)
 
-            return shard_map(
+            return jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(axis), P(axis)),
                 out_specs=(P(axis), P(axis)),
-                check_rep=False,
+                check_vma=False,
             )(states, ops)
 
         return update_segment

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .edges import append_one
+from .edges import append_edges
 from .prune import robust_prune
 from .quant import quant_write_rows
 from .search import greedy_search
@@ -67,11 +67,7 @@ def insert(state: GraphState, cfg: ANNConfig, x: jax.Array):
                 adj=s.adj.at[sslot].set(nout),
                 active=s.active.at[sslot].set(True),
             )
-
-            def rev(i, carry):
-                return append_one(carry, cfg, nout[i], slot)
-
-            s = lax.fori_loop(0, cfg.r, rev, s)
+            s = append_edges(s, cfg, nout, slot)
             return s, InsertStats(slot, res.n_comps, res.n_hops)
 
         return lax.cond(empty, first_point, grow, st)

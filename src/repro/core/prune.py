@@ -2,9 +2,10 @@
 
 The paper's loop removes the closest remaining candidate and occludes
 candidates that are much closer to it than to ``p``.  Here the candidate set
-is a fixed-width id vector (INVALID padded); ``r`` selection steps run as a
-``fori_loop``; each step issues one (C, D) @ (D,) matvec for the occlusion
-distances — O(r * C * D) total, the same asymptotics as the paper.
+is a fixed-width id vector (INVALID padded); at most ``r`` selection steps
+run as a ``while_loop``; each step issues one (C, D) @ (D,) matvec for the
+occlusion distances — O(r * C * D) total, the same asymptotics as the
+paper.
 """
 from __future__ import annotations
 
@@ -73,7 +74,11 @@ def robust_prune(
         alive = alive.at[j].set(False)
         return alive, out, n_out
 
-    _, out, _ = lax.fori_loop(
-        0, cfg.r, body, (alive, out, jnp.int32(0))
+    # stop once no candidate is left: the remaining steps of the r-step
+    # loop would select nothing
+    _, out, _ = lax.while_loop(
+        lambda c: jnp.any(c[0]) & (c[2] < cfg.r),
+        lambda c: body(None, c),
+        (alive, out, jnp.int32(0)),
     )
     return out

@@ -33,7 +33,8 @@ boundaries.  The super-step itself is a ``DistanceBackend`` surface
 body; the pallas engine overrides it with the fused multi-hop kernel
 (``kernels/beam_hop.py``) that keeps the (B, l) beam resident in VMEM
 across all H hops with per-lane early exit.  ``hop_fused = -1`` (default)
-auto-enables fusion exactly where the pallas engine is selected.
+resolves to unfused on every backend: the fused kernel does not compile
+with Mosaic yet (see ``resolved_hop_fused``).
 
 Per lane, the traversal is identical to per-query ``greedy_search``: the
 pop order, tie-breaks (first-minimum argmin, stable sort-merge), visited
@@ -67,8 +68,8 @@ from .types import INVALID, ANNConfig, GraphState, clip_ids, navigable
 # bucketing regression test asserts ragged batch sizes share one compile.
 TRACE_COUNTER = {"batched_greedy_search": 0}
 
-# Hops per super-step when ``cfg.hop_fused`` resolves to auto AND the
-# pallas engine is selected (see ``resolved_hop_fused``).
+# Hops per super-step the fused-engine benchmarks and parity tests pin
+# (``cfg.hop_fused`` auto resolves to unfused; see ``resolved_hop_fused``).
 DEFAULT_FUSED_HOPS = 4
 
 
@@ -121,12 +122,12 @@ def pad_batch(arr, b: int, fill=None):
 
 def resolved_hop_fused(cfg: ANNConfig) -> int:
     """The engine's hops-per-super-step: ``cfg.hop_fused`` when pinned
-    (0 = unfused), else auto — ``DEFAULT_FUSED_HOPS`` exactly where the
-    pallas engine is the resolved backend (the fused kernel's home; the
-    jnp/ref engines default unfused, matching the pre-fusion engine)."""
-    if cfg.hop_fused >= 0:
-        return cfg.hop_fused
-    return DEFAULT_FUSED_HOPS if resolve_backend(cfg).name == "pallas" else 0
+    (0 = unfused), else auto — 0 on every backend.  The fused multi-hop
+    kernel (``kernels/beam_hop.py``) runs in interpret mode only: Mosaic
+    refuses its vector gathers and in-kernel sort, so the pallas engine's
+    chip path is the per-hop loop over ``gather_distance_batched``, and an
+    explicit ``hop_fused > 0`` there raises (``PallasBackend``)."""
+    return max(cfg.hop_fused, 0)
 
 
 def make_hop_body(state: GraphState, cfg: ANNConfig, queries: jax.Array,

@@ -59,11 +59,11 @@ class ANNConfig:
     # the Pallas kernels on TPU and pure jnp elsewhere.
     backend: str = "auto"
     # Fused multi-hop beam engine (core/search_batched.py): hops per
-    # super-step of the batched hop loop.  -1 = auto (fused with the
-    # default hop count when the resolved backend is pallas, off
-    # elsewhere); 0 = off (one while_loop cond per hop); H >= 1 = fused,
-    # H hops per outer-loop iteration.  Traversal is lane-exact against
-    # the unfused engine for every H.
+    # super-step of the batched hop loop.  -1 = auto (off on every
+    # backend: the fused Pallas kernel does not compile with Mosaic yet);
+    # 0 = off (one while_loop cond per hop); H >= 1 = fused, H hops per
+    # outer-loop iteration.  Traversal is lane-exact against the unfused
+    # engine for every H.
     hop_fused: int = -1
     # Quantized memory tier (core/quant.py): maintain per-row symmetric
     # int8 codes next to the f32 table, traverse the beam on quantized

@@ -28,13 +28,18 @@ and reduced with one ``jnp.dot(x, q)`` MXU matvec; the l2 path adds the
 cached row norms (gathered in-kernel from the VMEM-resident norms row);
 invalid ids gather row 0 and mask to +inf afterwards.
 
-Mosaic caveats (interpret mode — the CI path — executes all of this as
-plain XLA): the top-(l) merge is expressed as ``lax.sort`` over the
-(l + R,) candidate row and the seen update as a sequential fori OR; a
-Mosaic deployment would swap these for an in-register bitonic network and
-a vectorized word-OR.  ``beam_hop_ref`` below is the self-contained
-pure-jnp oracle (same per-lane math, plain gathers instead of DMA) that
-the kernel parity tests run against.
+Interpret mode only: Mosaic (the TPU kernel compiler) refuses this kernel.
+Compiled for a v5e it fails on the (1, .) blocks of its per-lane operands,
+then on the value-level gathers of ``_lane_hop`` (the bitmap word gather
+in ``_getbit``, ``bi[i]`` / ``.at[i].set`` after the argmin, the norms and
+scales gathers), and behind those sit the in-kernel ``lax.sort`` over the
+(l + R,) candidate row and the (1, n_cap) norms block held in VMEM (4 MiB
+at n_cap = 2^20 before sublane padding, double-buffered).  The pallas
+engine therefore refuses ``hop_fused > 0`` under Mosaic
+(``core/backend.py``); a Mosaic version needs a sort network, no vector
+gathers and a VMEM budget at real n_cap.  ``beam_hop_ref`` below is the
+self-contained pure-jnp oracle (same per-lane math, plain gathers instead
+of DMA) that the kernel parity tests run against.
 """
 from __future__ import annotations
 
@@ -46,9 +51,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUMemorySpace -> MemorySpace around 0.5; accept both
-_ANY = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-_ANY = _ANY.ANY
+_ANY = pltpu.MemorySpace.ANY
 
 BIG = jnp.inf
 INVALID = -1

@@ -28,9 +28,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUMemorySpace -> MemorySpace around 0.5; accept both
-_ANY = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-_ANY = _ANY.ANY
+_ANY = pltpu.MemorySpace.ANY
 
 
 def _kernel(metric: str, has_norms: bool, tile_k: int, d: int,
@@ -154,30 +152,37 @@ def gather_distance_batched(
     """The 2-D-grid form of ``gather_distance`` for the batched beam engine:
     grid axis 0 walks the query batch, axis 1 the id tiles, so one kernel
     launch covers the whole (B, K) frontier-neighbourhood tile per hop
-    instead of B vmapped launches.  Per-(lane, tile) math is identical to
-    the 1-D kernel, so per-lane results match it bitwise."""
+    instead of B vmapped launches.  Lane and tile axes are LEADING dims of
+    the (B, 1, D) query and (B, K/TILE_K, 1, TILE_K) norm/output operands,
+    so every block's last two dims equal the array's — the Mosaic block
+    rule — at any B.  Per-(lane, tile) math is identical to the 1-D
+    kernel, so per-lane results match it bitwise."""
     bsz, k = ids.shape
     n, d = vectors.shape
     tile_k = min(tile_k, max(k, 1))
     pad = (-k) % tile_k
     ids_p = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
     kp = k + pad
+    n_tiles = kp // tile_k
     has_norms = norms is not None and metric == "l2"
     row_norms = (
         jnp.where(ids_p >= 0, norms[jnp.clip(ids_p, 0, n - 1)], 0.0)
         if has_norms
         else jnp.zeros((bsz, kp), jnp.float32)
     ).astype(jnp.float32)
+    tiled = (bsz, n_tiles, 1, tile_k)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(bsz, kp // tile_k),
+        grid=(bsz, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, d), lambda b, i, ids: (b, 0)),
-            pl.BlockSpec((1, tile_k), lambda b, i, ids: (b, i)),
+            pl.BlockSpec((None, 1, d), lambda b, i, ids: (b, 0, 0)),
+            pl.BlockSpec((None, None, 1, tile_k),
+                         lambda b, i, ids: (b, i, 0, 0)),
             pl.BlockSpec(memory_space=_ANY),
         ],
-        out_specs=pl.BlockSpec((1, tile_k), lambda b, i, ids: (b, i)),
+        out_specs=pl.BlockSpec((None, None, 1, tile_k),
+                               lambda b, i, ids: (b, i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((tile_k, d), jnp.float32),
             pltpu.SemaphoreType.DMA,
@@ -186,8 +191,9 @@ def gather_distance_batched(
     out = pl.pallas_call(
         functools.partial(_kernel_batched, metric, has_norms, tile_k, kp, d),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, kp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(tiled, jnp.float32),
         interpret=interpret,
-    )(ids_p.reshape(-1), queries.astype(jnp.float32), row_norms, vectors)
-    out = out[:, :k]
+    )(ids_p.reshape(-1), queries.astype(jnp.float32)[:, None, :],
+      row_norms.reshape(tiled), vectors)
+    out = out.reshape(bsz, kp)[:, :k]
     return jnp.where(ids >= 0, out, jnp.inf)

@@ -19,9 +19,11 @@ kernel, and nothing else:
     path's ``GraphState.norms``.
 
 VMEM budget: TILE_K * D bytes of int8 scratch (64 x 128 = 8 KiB) — a
-quarter of the f32 kernel's tile.  On a Mosaic deployment D should be a
-multiple of 128 lanes and TILE_K of 32 sublanes (the int8 tile minimum);
-interpret-mode tests accept any shape.
+quarter of the f32 kernel's tile.  Interpret mode only: Mosaic refuses the
+single-row int8 DMA (int8 tiles are (32, 128), so a one-row slice is not
+aligned to the tiling), and the pallas engine refuses ``quantized`` under
+Mosaic (``core/backend.py``).  Viewing the code table as 32-bit words is
+one route to an aligned gather.
 """
 from __future__ import annotations
 
@@ -33,9 +35,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUMemorySpace -> MemorySpace around 0.5; accept both
-_ANY = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-_ANY = _ANY.ANY
+_ANY = pltpu.MemorySpace.ANY
 
 
 def _kernel_batched_q(metric: str, tile_k: int, kp: int, d: int,

@@ -28,7 +28,6 @@ exactly the state an uninterrupted run would have had:
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 
@@ -41,7 +40,8 @@ def main(argv=None) -> None:
     ap.add_argument("--queries", type=int, default=32)
     ap.add_argument("--mode", default="ip", choices=["ip", "fresh"])
     ap.add_argument("--shards", type=int, default=0,
-                    help="run the shard_map fan-out index on N host devices")
+                    help="run the shard_map fan-out index on N devices "
+                         "(host devices under JAX_PLATFORMS=cpu)")
     ap.add_argument("--deadline-ms", type=float, default=5.0,
                     help="dynamic-batcher admission deadline per query")
     ap.add_argument("--bucket", type=int, default=32,
@@ -55,12 +55,12 @@ def main(argv=None) -> None:
                          "requires --checkpoint-dir to recover")
     args = ap.parse_args(argv)
 
-    if args.shards:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.shards}"
-        )
+    from .compile_cache import enable_compile_cache
+    from .mesh import shard_mesh
 
-    import jax
+    # the mesh first: on CPU its host devices must precede backend start-up
+    mesh = shard_mesh(args.shards) if args.shards else None
+    enable_compile_cache()
 
     from ..checkpoint import CheckpointManager
     from ..configs.ann import test_scale
@@ -82,8 +82,6 @@ def main(argv=None) -> None:
     if args.shards:
         from ..core.distributed import ShardedIndex
         from ..serving import ShardedEngine
-
-        mesh = jax.make_mesh((args.shards,), ("shard",))
 
         def fresh_index():
             return ShardedIndex(cfg, mesh, max_external_id=max_ext)

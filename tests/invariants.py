@@ -6,7 +6,7 @@ strings — empty means healthy.  It encodes the structural contracts every
 update policy must preserve:
 
 - adjacency hygiene: ids in range, no self loops, no duplicates within a
-  row, rows front-compacted (``append_one`` writes at ``row_count``);
+  row, rows front-compacted (``append_edges`` writes at ``row_count``);
 - no out-edges into free slots, ever.  Edges into tombstoned (fresh) or
   quarantined (ip) slots are legal only pre-consolidation, and only for
   the policy that produces that limbo state; the ``local`` policy promises

@@ -12,6 +12,7 @@ tests (``core/bitset.py``) and kernel-vs-oracle parity for
 ``kernels/beam_hop.py``.  ``N_CAP`` is deliberately NOT a multiple of 32
 so the packed bitmap's tail word is always in play.
 """
+import dataclasses
 import functools
 
 import jax.numpy as jnp
@@ -177,16 +178,43 @@ def test_fused_masked_and_empty_lanes(backend):
 
 
 def test_hop_fused_auto_selection():
-    """-1 resolves to the fused default exactly where pallas is the
-    resolved backend; explicit values always win."""
+    """-1 resolves to unfused on every backend (the fused kernel does not
+    compile with Mosaic yet); explicit values always win."""
     assert resolved_hop_fused(_cfg("l2", "jnp")) == 0
     assert resolved_hop_fused(_cfg("l2", "ref")) == 0
-    assert resolved_hop_fused(_cfg("l2", "pallas")) == DEFAULT_FUSED_HOPS
+    assert resolved_hop_fused(_cfg("l2", "pallas")) == 0
     assert resolved_hop_fused(_cfg("l2", "jnp", 5)) == 5
     assert resolved_hop_fused(_cfg("l2", "pallas", 0)) == 0
     assert resolved_hop_fused(_cfg("l2", "pallas", 2)) == 2
     with pytest.raises(AssertionError):
         _cfg("l2", "jnp", -2)
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+def test_fused_request_refused_under_mosaic(monkeypatch, metric):
+    """An explicit hop_fused > 0 on the pallas engine, with the kernels
+    compiled by Mosaic, raises a clear error naming the knob instead of
+    silently running another engine."""
+    from repro.core import backend as backend_mod
+
+    monkeypatch.setattr(backend_mod.PallasBackend, "interpret", False)
+    cfg = _cfg(metric, "pallas", DEFAULT_FUSED_HOPS)
+    state = init_state(cfg)
+    with pytest.raises(NotImplementedError, match="hop_fused"):
+        batched_greedy_search(state, cfg, jnp.zeros((2, DIM)), k=5, l=16)
+
+
+@pytest.mark.parametrize("h", [0, DEFAULT_FUSED_HOPS])
+def test_quantized_refused_under_mosaic(monkeypatch, h):
+    """The int8 gather kernel's single-row DMAs do not compile with Mosaic:
+    quantized=True on the pallas engine refuses there, naming the knob."""
+    from repro.core import backend as backend_mod
+
+    monkeypatch.setattr(backend_mod.PallasBackend, "interpret", False)
+    cfg = dataclasses.replace(_cfg("l2", "pallas", h), quantized=True)
+    state = init_state(cfg)
+    with pytest.raises(NotImplementedError, match="quantized"):
+        batched_greedy_search(state, cfg, jnp.zeros((2, DIM)), k=5, l=16)
 
 
 # ---------------------------------------------------------------------------
