@@ -71,6 +71,16 @@ from .delete import ip_delete_many, lazy_delete_many, local_delete_many
 from .insert import insert_many
 from .search import search_batch
 from .search_batched import next_bucket
+from .spans import (
+    CONSOLIDATE,
+    MAP,
+    SEARCH,
+    SEARCH_MAP_IDS,
+    TRACE_COUNTER,
+    TRACE_UNROLL,
+    device_scope,
+    host_span,
+)
 from .types import (
     INVALID,
     KIND_DELETE,
@@ -87,16 +97,6 @@ from .types import (
     stack_update_batches,
     take_update_lanes,
 )
-
-# Incremented once per trace of ``apply``/``apply_segment`` (not per call):
-# the bucketing regression tests assert ragged batch sizes — and ragged
-# segment lengths — share one compiled program per bucket.
-TRACE_COUNTER = {"apply": 0, "apply_segment": 0}
-
-# (T, B) -> resolved unroll, recorded each time ``apply_segment`` traces
-# with ``unroll=None``; the auto-unroll regression test pins the bucket
-# keys actually chosen.
-TRACE_UNROLL = {}
 
 
 def auto_unroll(t: int, b: int) -> int:
@@ -521,93 +521,96 @@ def _apply_impl(
     """The traced ``apply`` body, shared verbatim by the per-op front door
     and the ``lax.scan`` step of ``apply_segment`` (segment-vs-loop parity
     is bit parity because this IS the same program)."""
+    # every operation here but the two phases' own runs under ``ann.map``
     b = batch.kind.shape[0]
     e_cap = state.ext2slot.shape[0]
-    ext_ok = (batch.ext_id >= 0) & (batch.ext_id < e_cap)
-    sext = jnp.clip(batch.ext_id, 0, e_cap - 1)
-    is_ins = batch.valid & ext_ok & (batch.kind == KIND_INSERT)
-    is_del = batch.valid & ext_ok & (batch.kind == KIND_DELETE)
-    if split is not None:
-        lane = jnp.arange(b)
-        is_ins = is_ins & (lane < split)
-        is_del = is_del & (lane >= split)
+    with device_scope(MAP):
+        ext_ok = (batch.ext_id >= 0) & (batch.ext_id < e_cap)
+        sext = jnp.clip(batch.ext_id, 0, e_cap - 1)
+        is_ins = batch.valid & ext_ok & (batch.kind == KIND_INSERT)
+        is_del = batch.valid & ext_ok & (batch.kind == KIND_DELETE)
+        if split is not None:
+            lane = jnp.arange(b)
+            is_ins = is_ins & (lane < split)
+            is_del = is_del & (lane >= split)
+        ins_lanes = slice(None) if split is None else slice(None, split)
+        ins_vectors, ins_valid = batch.vector[ins_lanes], is_ins[ins_lanes]
 
     # ---- insert phase ------------------------------------------------------
     ins_fn = insert_many if sequential else insert_many_batched
-    if split is None:
-        graph, ins_stats = ins_fn(state.graph, cfg, batch.vector, is_ins)
-        ins_slots = ins_stats.slot                  # INVALID on masked/full
-        ins_comps_lane = ins_stats.n_comps
-    else:
-        graph, ins_stats = ins_fn(
-            state.graph, cfg, batch.vector[:split], is_ins[:split]
-        )
-        tail = jnp.full((b - split,), INVALID, jnp.int32)
-        ins_slots = jnp.concatenate([ins_stats.slot, tail])
-        ins_comps_lane = jnp.concatenate(
-            [ins_stats.n_comps.astype(jnp.int32), jnp.zeros_like(tail)]
-        )
-    ok_ins = is_ins & (ins_slots >= 0)
+    graph, ins_stats = ins_fn(state.graph, cfg, ins_vectors, ins_valid)
 
-    # rebind: clear the stale reverse entry of a re-inserted external id
-    prev = jnp.where(ok_ins, state.ext2slot[sext], INVALID)
-    slot2ext = state.slot2ext.at[
-        jnp.where(prev >= 0, clip_ids(prev, cfg.n_cap), cfg.n_cap)
-    ].set(INVALID, mode="drop")
-    ext2slot = state.ext2slot.at[
-        jnp.where(ok_ins, sext, e_cap)
-    ].set(ins_slots, mode="drop")
-    slot2ext = slot2ext.at[
-        jnp.where(ok_ins, clip_ids(ins_slots, cfg.n_cap), cfg.n_cap)
-    ].set(batch.ext_id, mode="drop")
+    with device_scope(MAP):
+        if split is None:
+            ins_slots = ins_stats.slot              # INVALID on masked/full
+            ins_comps_lane = ins_stats.n_comps
+        else:
+            tail = jnp.full((b - split,), INVALID, jnp.int32)
+            ins_slots = jnp.concatenate([ins_stats.slot, tail])
+            ins_comps_lane = jnp.concatenate(
+                [ins_stats.n_comps.astype(jnp.int32), jnp.zeros_like(tail)]
+            )
+        ok_ins = is_ins & (ins_slots >= 0)
+
+        # rebind: clear the stale reverse entry of a re-inserted external id
+        prev = jnp.where(ok_ins, state.ext2slot[sext], INVALID)
+        slot2ext = state.slot2ext.at[
+            jnp.where(prev >= 0, clip_ids(prev, cfg.n_cap), cfg.n_cap)
+        ].set(INVALID, mode="drop")
+        ext2slot = state.ext2slot.at[
+            jnp.where(ok_ins, sext, e_cap)
+        ].set(ins_slots, mode="drop")
+        slot2ext = slot2ext.at[
+            jnp.where(ok_ins, clip_ids(ins_slots, cfg.n_cap), cfg.n_cap)
+        ].set(batch.ext_id, mode="drop")
+
+        # resolve deletes against the POST-insert map: a batch may delete an
+        # id that an earlier lane of the same batch inserted
+        del_slots = jnp.where(is_del, ext2slot[sext], INVALID)
+        del_lanes = del_slots if split is None else del_slots[split:]
 
     # ---- delete phase (policy-owned strategy) ------------------------------
-    # resolve against the POST-insert map: a batch may delete an id that an
-    # earlier lane of the same batch inserted
-    del_slots = jnp.where(is_del, ext2slot[sext], INVALID)
-    if split is None:
-        graph, del_stats = pol.delete_many(
-            graph, cfg, del_slots, sequential=sequential
-        )
-        del_ok_lane = del_stats.ok
-        del_comps_lane = del_stats.n_comps
-    else:
-        graph, del_stats = pol.delete_many(
-            graph, cfg, del_slots[split:], sequential=sequential
-        )
-        head_f = jnp.zeros((split,), bool)
-        del_ok_lane = jnp.concatenate([head_f, del_stats.ok])
-        del_comps_lane = jnp.concatenate(
-            [jnp.zeros((split,), jnp.int32),
-             del_stats.n_comps.astype(jnp.int32)]
-        )
-    ok_del = is_del & del_ok_lane
-    ext2slot = ext2slot.at[
-        jnp.where(ok_del, sext, e_cap)
-    ].set(INVALID, mode="drop")
-    slot2ext = slot2ext.at[
-        jnp.where(ok_del, clip_ids(del_slots, cfg.n_cap), cfg.n_cap)
-    ].set(INVALID, mode="drop")
+    graph, del_stats = pol.delete_many(graph, cfg, del_lanes,
+                                       sequential=sequential)
 
-    # ---- counters + per-lane result ---------------------------------------
-    ins_comps = jnp.where(is_ins, ins_comps_lane, 0).astype(jnp.int32)
-    del_comps = jnp.where(is_del, del_comps_lane, 0).astype(jnp.int32)
-    new_state = IndexState(
-        graph=graph,
-        ext2slot=ext2slot,
-        slot2ext=slot2ext,
-        n_inserts=state.n_inserts + jnp.sum(ok_ins).astype(jnp.int32),
-        n_deletes=state.n_deletes + jnp.sum(ok_del).astype(jnp.int32),
-        insert_comps=state.insert_comps + jnp.sum(ins_comps),
-        delete_comps=state.delete_comps + jnp.sum(del_comps),
-    )
-    result = ApplyResult(
-        slot=jnp.where(
-            ok_ins, ins_slots, jnp.where(is_del, del_slots, INVALID)
-        ),
-        ok=ok_ins | ok_del,
-        n_comps=ins_comps + del_comps,
-    )
+    with device_scope(MAP):
+        if split is None:
+            del_ok_lane = del_stats.ok
+            del_comps_lane = del_stats.n_comps
+        else:
+            head_f = jnp.zeros((split,), bool)
+            del_ok_lane = jnp.concatenate([head_f, del_stats.ok])
+            del_comps_lane = jnp.concatenate(
+                [jnp.zeros((split,), jnp.int32),
+                 del_stats.n_comps.astype(jnp.int32)]
+            )
+        ok_del = is_del & del_ok_lane
+        ext2slot = ext2slot.at[
+            jnp.where(ok_del, sext, e_cap)
+        ].set(INVALID, mode="drop")
+        slot2ext = slot2ext.at[
+            jnp.where(ok_del, clip_ids(del_slots, cfg.n_cap), cfg.n_cap)
+        ].set(INVALID, mode="drop")
+
+        # ---- counters + per-lane result -----------------------------------
+        ins_comps = jnp.where(is_ins, ins_comps_lane, 0).astype(jnp.int32)
+        del_comps = jnp.where(is_del, del_comps_lane, 0).astype(jnp.int32)
+        new_state = IndexState(
+            graph=graph,
+            ext2slot=ext2slot,
+            slot2ext=slot2ext,
+            n_inserts=state.n_inserts + jnp.sum(ok_ins).astype(jnp.int32),
+            n_deletes=state.n_deletes + jnp.sum(ok_del).astype(jnp.int32),
+            insert_comps=state.insert_comps + jnp.sum(ins_comps),
+            delete_comps=state.delete_comps + jnp.sum(del_comps),
+        )
+        result = ApplyResult(
+            slot=jnp.where(
+                ok_ins, ins_slots, jnp.where(is_del, del_slots, INVALID)
+            ),
+            ok=ok_ins | ok_del,
+            n_comps=ins_comps + del_comps,
+        )
     return new_state, result
 
 
@@ -752,14 +755,15 @@ def segment_scan(
         st, res = _apply_impl(st, cfg, op, pol, sequential, split)
         consolidated = needs = jnp.bool_(False)
         if consolidate:
-            trig = pol.should_consolidate_device(cfg, st.graph)
-            if pol.device_consolidation:
-                st = st._replace(
-                    graph=device_sweep(st.graph, cfg, pol, trig)
-                )
-                consolidated = trig
-            else:
-                needs = trig
+            with device_scope(CONSOLIDATE):
+                trig = pol.should_consolidate_device(cfg, st.graph)
+                if pol.device_consolidation:
+                    st = st._replace(
+                        graph=device_sweep(st.graph, cfg, pol, trig)
+                    )
+                    consolidated = trig
+                else:
+                    needs = trig
         return st, SegmentResult(
             slot=res.slot, ok=res.ok, n_comps=res.n_comps,
             consolidated=consolidated, needs_consolidation=needs,
@@ -981,12 +985,19 @@ def search(
 ):
     """Query the handle; returns ``(ext_ids, dists, SearchResult)`` with the
     slot -> external-id mapping applied on device (the ``SearchResult``
-    keeps slot ids for state-level consumers)."""
-    res = search_batch(state.graph, cfg, queries, k=k, l=l or cfg.l_search)
-    sids = res.topk_ids
-    ext = jnp.where(
-        sids >= 0, state.slot2ext[clip_ids(sids, cfg.n_cap)], INVALID
-    )
+    keeps slot ids for state-level consumers).
+
+    Host spans (``core/spans.py``): ``ann.search`` over the whole call, and
+    in it ``ann.search.pad`` and ``ann.search.dispatch`` (in
+    ``search_batch``), then ``ann.search.map_ids``."""
+    with host_span(SEARCH):
+        res = search_batch(state.graph, cfg, queries, k=k,
+                           l=l or cfg.l_search)
+        with host_span(SEARCH_MAP_IDS):
+            sids = res.topk_ids
+            ext = jnp.where(
+                sids >= 0, state.slot2ext[clip_ids(sids, cfg.n_cap)], INVALID
+            )
     return ext, res.topk_dists, res
 
 

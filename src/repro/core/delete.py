@@ -33,13 +33,13 @@ from jax import lax
 from .backend import BIG, resolve_backend
 from .edges import append_edges, remove_target_everywhere, remove_target_rows
 from .search import greedy_search
+from .spans import DELETE_REPAIR, DELETE_SEARCH, device_scope
 from .types import INVALID, ANNConfig, GraphState, clip_ids
 
 
 class DeleteStats(NamedTuple):
     ok: jax.Array       # bool[] point existed and was removed
     n_comps: jax.Array  # i32[]
-    n_in: jax.Array     # i32[] approximated in-neighbours found
 
 
 def _topc_candidates(state, cfg, src_ids, cand_ids, c):
@@ -59,11 +59,16 @@ def ip_delete(state: GraphState, cfg: ANNConfig, p: jax.Array):
     valid = (p >= 0) & state.active[sp]
 
     def no_op(st: GraphState):
-        return st, DeleteStats(jnp.bool_(False), jnp.int32(0), jnp.int32(0))
+        return st, DeleteStats(jnp.bool_(False), jnp.int32(0))
 
     def do_delete(st: GraphState):
-        x_p = st.vectors[sp]
-        res = greedy_search(st, cfg, x_p, k=cfg.k_delete, l=cfg.l_delete)
+        with device_scope(DELETE_SEARCH):
+            res = greedy_search(st, cfg, st.vectors[sp], k=cfg.k_delete,
+                                l=cfg.l_delete)
+        with device_scope(DELETE_REPAIR):
+            return repair(st, res)
+
+    def repair(st: GraphState, res):
         vis = jnp.where(res.visited_ids == p, INVALID, res.visited_ids)
         cands = jnp.where(res.topk_ids == p, INVALID, res.topk_ids)
         nout_p = st.adj[sp]
@@ -71,7 +76,6 @@ def ip_delete(state: GraphState, cfg: ANNConfig, p: jax.Array):
         # --- approximate in-neighbours & their replacement edges -----------
         vis_rows = st.adj[clip_ids(vis, cfg.n_cap)]          # (V, r)
         in_mask = jnp.any(vis_rows == p, axis=1) & (vis >= 0)
-        n_in = jnp.sum(in_mask).astype(jnp.int32)
         cz = _topc_candidates(st, cfg, vis, cands, cfg.n_copies)   # (V, c)
 
         # remove z -> p for every approximated in-neighbour
@@ -99,7 +103,7 @@ def ip_delete(state: GraphState, cfg: ANNConfig, p: jax.Array):
         # distance comps: search + (V + r) * k selection matrices
         extra = (res.n_visited + jnp.sum(nout_p >= 0)) * cfg.k_delete
         return st, DeleteStats(
-            jnp.bool_(True), res.n_comps + extra.astype(jnp.int32), n_in
+            jnp.bool_(True), res.n_comps + extra.astype(jnp.int32)
         )
 
     return lax.cond(valid, do_delete, no_op, state)
@@ -176,7 +180,7 @@ def local_delete(state: GraphState, cfg: ANNConfig, p: jax.Array):
     valid = (p >= 0) & state.active[sp]
 
     def no_op(st: GraphState):
-        return st, DeleteStats(jnp.bool_(False), jnp.int32(0), jnp.int32(0))
+        return st, DeleteStats(jnp.bool_(False), jnp.int32(0))
 
     def do_delete(st: GraphState):
         b_in = min(cfg.resolved_local_in_cap(), cfg.n_cap)
@@ -185,7 +189,6 @@ def local_delete(state: GraphState, cfg: ANNConfig, p: jax.Array):
         # --- exact in-neighbourhood off the topology -----------------------
         in_rows = jnp.any(st.adj == p, axis=1)
         in_rows = in_rows.at[sp].set(False)      # no self loops, but be safe
-        n_in = jnp.sum(in_rows).astype(jnp.int32)
         z_idx = jnp.where(
             in_rows, jnp.arange(cfg.n_cap, dtype=jnp.int32), cfg.n_cap
         )
@@ -213,9 +216,7 @@ def local_delete(state: GraphState, cfg: ANNConfig, p: jax.Array):
             start=new_start,
         )
         comps = jnp.sum(z_ids >= 0) * jnp.sum(nout_p >= 0)
-        return st, DeleteStats(
-            jnp.bool_(True), comps.astype(jnp.int32), n_in
-        )
+        return st, DeleteStats(jnp.bool_(True), comps.astype(jnp.int32))
 
     return lax.cond(valid, do_delete, no_op, state)
 
@@ -231,7 +232,8 @@ def local_delete_many(state: GraphState, cfg: ANNConfig, ps: jax.Array):
         st, stats = local_delete(st, cfg, p)
         return st, stats
 
-    return lax.scan(step, state, ps)
+    with device_scope(DELETE_REPAIR):
+        return lax.scan(step, state, ps)
 
 
 # ---------------------------------------------------------------------------
@@ -253,10 +255,10 @@ def lazy_delete(state: GraphState, cfg: ANNConfig, p: jax.Array):
             tombstone=st.tombstone.at[sp].set(True),
             n_active=st.n_active - 1,
             n_pending=st.n_pending + 1,
-        ), DeleteStats(jnp.bool_(True), jnp.int32(0), jnp.int32(0))
+        ), DeleteStats(jnp.bool_(True), jnp.int32(0))
 
     def no_op(st: GraphState):
-        return st, DeleteStats(jnp.bool_(False), jnp.int32(0), jnp.int32(0))
+        return st, DeleteStats(jnp.bool_(False), jnp.int32(0))
 
     return lax.cond(valid, do, no_op, state)
 
@@ -267,4 +269,5 @@ def lazy_delete_many(state: GraphState, cfg: ANNConfig, ps: jax.Array):
         st, stats = lazy_delete(st, cfg, p)
         return st, stats
 
-    return lax.scan(step, state, ps)
+    with device_scope(DELETE_REPAIR):
+        return lax.scan(step, state, ps)

@@ -97,30 +97,12 @@ from .consolidate import consolidate_stacked
 from .grow import ensure_capacity
 from .persist import restore_index, save_index
 from .search_batched import batched_greedy_search, merge_topk, next_bucket
+from .spans import TRACE_COUNTER, TRACE_SHAPES
 from .types import (
     INVALID, KIND_INSERT, ANNConfig, IndexState, UpdateBatch, clip_ids,
     init_index_state, noop_update_batch,
 )
 
-# Incremented once per trace (not per call) of each SPMD program, with the
-# traced op-tensor shape recorded in TRACE_SHAPES: the sharding tests pin
-# both the power-of-two bucketing discipline (ragged batches share
-# compiles) and the compact-routing contract (per-shard lane width <=
-# next_bucket(ceil(B / S)), S-fold smaller than the replicated width).
-# ``segment_pack`` is the one host-side entry: it counts owner-compaction
-# packs of individual stream steps (``update_stream`` packs every step
-# EXACTLY once, at plan time — the owner-aware planning test pins that no
-# step is ever re-packed per segment).
-TRACE_COUNTER = {
-    "update_compact": 0,
-    "segment_compact": 0,
-    "segment_pack": 0,
-    "update_replicate": 0,
-    "segment_replicate": 0,
-    "search_replicate": 0,
-    "search_partition": 0,
-}
-TRACE_SHAPES: dict = {k: [] for k in TRACE_COUNTER}
 
 
 def _row(tree, g: int):

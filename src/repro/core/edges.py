@@ -6,6 +6,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .prune import robust_prune
+from .spans import EDGES_APPEND, device_scope
 from .types import (
     INVALID,
     ANNConfig,
@@ -45,6 +46,7 @@ def _appended_row(state: GraphState, cfg: ANNConfig, v, u, row):
 LANES = 64  # rows an append round prunes per vmapped batch
 
 
+@device_scope(EDGES_APPEND)
 def append_edges(state: GraphState, cfg: ANNConfig, vs, us) -> GraphState:
     """Add the edges ``vs[k] -> us[k]`` in order k (broadcast together,
     then flattened row-major), each as Algorithm 2 lines 5-8 (see

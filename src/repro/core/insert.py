@@ -12,13 +12,13 @@ from .edges import append_edges
 from .prune import robust_prune
 from .quant import quant_write_rows
 from .search import greedy_search
+from .spans import INSERT_LINK, INSERT_SEARCH, device_scope
 from .types import INVALID, ANNConfig, GraphState, clip_ids
 
 
 class InsertStats(NamedTuple):
     slot: jax.Array     # i32[] slot assigned (INVALID if capacity exhausted)
     n_comps: jax.Array  # i32[] distance computations
-    n_hops: jax.Array   # i32[]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -32,7 +32,7 @@ def insert(state: GraphState, cfg: ANNConfig, x: jax.Array):
     x = x.astype(state.vectors.dtype)
 
     def no_capacity(st: GraphState):
-        return st, InsertStats(jnp.int32(INVALID), jnp.int32(0), jnp.int32(0))
+        return st, InsertStats(jnp.int32(INVALID), jnp.int32(0))
 
     def do_insert(st: GraphState):
         st = st._replace(
@@ -56,19 +56,21 @@ def insert(state: GraphState, cfg: ANNConfig, x: jax.Array):
                 start=slot,
                 active=s.active.at[sslot].set(True),
             )
-            return s, InsertStats(slot, jnp.int32(0), jnp.int32(0))
+            return s, InsertStats(slot, jnp.int32(0))
 
         def grow(s: GraphState):
-            res = greedy_search(s, cfg, x, k=1, l=cfg.l_build)
-            nout = robust_prune(
-                s, cfg, x, res.visited_ids, res.visited_dists, p_id=slot
-            )
-            s = s._replace(
-                adj=s.adj.at[sslot].set(nout),
-                active=s.active.at[sslot].set(True),
-            )
-            s = append_edges(s, cfg, nout, slot)
-            return s, InsertStats(slot, res.n_comps, res.n_hops)
+            with device_scope(INSERT_SEARCH):
+                res = greedy_search(s, cfg, x, k=1, l=cfg.l_build)
+            with device_scope(INSERT_LINK):
+                nout = robust_prune(
+                    s, cfg, x, res.visited_ids, res.visited_dists, p_id=slot
+                )
+                s = s._replace(
+                    adj=s.adj.at[sslot].set(nout),
+                    active=s.active.at[sslot].set(True),
+                )
+                s = append_edges(s, cfg, nout, slot)
+            return s, InsertStats(slot, res.n_comps)
 
         return lax.cond(empty, first_point, grow, st)
 
@@ -92,9 +94,7 @@ def insert_many(state: GraphState, cfg: ANNConfig, xs: jax.Array,
         x, ok = args
 
         def skip(s):
-            return s, InsertStats(
-                jnp.int32(INVALID), jnp.int32(0), jnp.int32(0)
-            )
+            return s, InsertStats(jnp.int32(INVALID), jnp.int32(0))
 
         st, stats = lax.cond(ok, lambda s: insert(s, cfg, x), skip, st)
         return st, stats

@@ -17,10 +17,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from .backend import BIG, resolve_backend
+from .spans import PRUNE, device_scope
 from .types import INVALID, ANNConfig, GraphState, clip_ids, mask_duplicates
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
+@device_scope(PRUNE)
 def robust_prune(
     state: GraphState,
     cfg: ANNConfig,

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .backend import BIG, resolve_backend
+from .spans import SEARCH_DISPATCH, SEARCH_PAD, host_span
 from .types import INVALID, ANNConfig, GraphState, clip_ids, navigable
 
 
@@ -241,16 +242,18 @@ def search_batch(
 
     b = queries.shape[0]
     batched_fn = _lift_distance_fn(distance_fn) if distance_fn else None
-    qs = pad_batch(queries, b) if bucket else queries
-    # padding lanes are masked dead (empty beam, zero comps, zero hops)
-    # instead of running a throwaway zero-query search to convergence.
-    # The mask is passed even when b fills the bucket exactly, so every
-    # batch size of a bucket shares ONE trace (valid=None is a different
-    # jit key than a bool[B] mask).
-    valid = jnp.arange(qs.shape[0]) < b if bucket else None
-    res = batched_greedy_search(
-        state, cfg, qs, k=k, l=l, distance_fn=batched_fn, valid=valid
-    )
+    with host_span(SEARCH_PAD):
+        qs = pad_batch(queries, b) if bucket else queries
+        # padding lanes are masked dead (empty beam, zero comps, zero hops)
+        # instead of running a throwaway zero-query search to convergence.
+        # The mask is passed even when b fills the bucket exactly, so every
+        # batch size of a bucket shares ONE trace (valid=None is a different
+        # jit key than a bool[B] mask).
+        valid = jnp.arange(qs.shape[0]) < b if bucket else None
+    with host_span(SEARCH_DISPATCH):
+        res = batched_greedy_search(
+            state, cfg, qs, k=k, l=l, distance_fn=batched_fn, valid=valid
+        )
     if qs.shape[0] != b:
         res = jax.tree.map(lambda x: x[:b], res)
     return res

@@ -62,11 +62,10 @@ from jax import lax
 from . import bitset
 from .backend import BIG, resolve_backend
 from .search import SearchResult
+from .spans import (SEARCH_HOPS, SEARCH_SELECT, TRACE_COUNTER,
+                    device_scope)
 from .types import INVALID, ANNConfig, GraphState, clip_ids, navigable
 
-# Incremented once per trace of the shared hop loop (not per call): the
-# bucketing regression test asserts ragged batch sizes share one compile.
-TRACE_COUNTER = {"batched_greedy_search": 0}
 
 # Hops per super-step the fused-engine benchmarks and parity tests pin
 # (``cfg.hop_fused`` auto resolves to unfused; see ``resolved_hop_fused``).
@@ -282,99 +281,105 @@ def batched_greedy_search(
     )
     returnable = state.active
 
-    b = queries.shape[0]
-    starts = jnp.broadcast_to(state.start, (b,))
-    if valid is not None:
-        starts = jnp.where(valid, starts, INVALID)
-    d0 = dist_fn(state, cfg, queries, starts[:, None])[:, 0]
+    with device_scope(SEARCH_HOPS):
+        b = queries.shape[0]
+        starts = jnp.broadcast_to(state.start, (b,))
+        if valid is not None:
+            starts = jnp.where(valid, starts, INVALID)
+        d0 = dist_fn(state, cfg, queries, starts[:, None])[:, 0]
 
-    beam_ids = jnp.full((b, l), INVALID, jnp.int32).at[:, 0].set(starts)
-    beam_dists = jnp.full((b, l), BIG, jnp.float32).at[:, 0].set(
-        jnp.where(starts >= 0, d0, BIG)
-    )
-    seen = bitset.setbits_rows(
-        bitset.empty_rows(b, cfg.n_cap),
-        clip_ids(starts, cfg.n_cap)[:, None],
-        (starts >= 0)[:, None],
-    )
-
-    init = _BLoop(
-        beam_ids=beam_ids,
-        beam_dists=beam_dists,
-        beam_exp=jnp.zeros((b, l), bool),
-        seen=seen,
-        vis_ids=jnp.full((b, max_visits), INVALID, jnp.int32),
-        vis_dists=jnp.full((b, max_visits), BIG, jnp.float32),
-        n_vis=jnp.zeros((b,), jnp.int32),
-        n_comps=jnp.where(starts >= 0, 1, 0).astype(jnp.int32),
-        n_hops=jnp.zeros((b,), jnp.int32),
-    )
-
-    def lane_active(s: _BLoop):
-        frontier = (
-            (s.beam_ids >= 0) & ~s.beam_exp & jnp.isfinite(s.beam_dists)
+        beam_ids = jnp.full((b, l), INVALID, jnp.int32).at[:, 0].set(starts)
+        beam_dists = jnp.full((b, l), BIG, jnp.float32).at[:, 0].set(
+            jnp.where(starts >= 0, d0, BIG)
         )
-        return jnp.any(frontier, axis=1) & (s.n_hops < max_visits)
-
-    def cond(s: _BLoop):
-        return jnp.any(lane_active(s))
-
-    h = resolved_hop_fused(cfg)
-    if h <= 0:
-        body = make_hop_body(state, cfg, queries, dist_fn, l=l,
-                             max_visits=max_visits)
-    elif distance_fn is not None:
-        # a custom distance_fn has no kernel; fuse through the generic
-        # super-step so the override still sees every hop's distances
-        def body(s):
-            return superstep_reference(dist_fn, state, cfg, queries, s,
-                                       h=h, l=l, max_visits=max_visits)
-    elif use_q:
-        def body(s):
-            return backend.beam_superstep_q(state, cfg, queries, s, h=h,
-                                            l=l, max_visits=max_visits)
-    else:
-        def body(s):
-            return backend.beam_superstep(state, cfg, queries, s, h=h,
-                                          l=l, max_visits=max_visits)
-
-    out = lax.while_loop(cond, body, init)
-
-    # --- final top-k over each lane's beam, filtered to live vertices --------
-    ret = returnable[clip_ids(out.beam_ids, cfg.n_cap)] & (out.beam_ids >= 0)
-    if use_q:
-        # exact rescore (FreshDiskANN): re-rank the surviving beam against
-        # the full-precision table so the selection (and the reported
-        # distances) never carry quantization error; one (B, l) exact tile
-        # per query batch vs. the hops' many (B, R) quantized tiles
-        beam_d = backend.dists_to_ids_batched(
-            state, cfg, queries, jnp.where(ret, out.beam_ids, INVALID)
+        seen = bitset.setbits_rows(
+            bitset.empty_rows(b, cfg.n_cap),
+            clip_ids(starts, cfg.n_cap)[:, None],
+            (starts >= 0)[:, None],
         )
-        out = out._replace(
-            beam_dists=beam_d,
-            n_comps=out.n_comps + jnp.sum(ret, axis=1).astype(jnp.int32),
+
+        init = _BLoop(
+            beam_ids=beam_ids,
+            beam_dists=beam_dists,
+            beam_exp=jnp.zeros((b, l), bool),
+            seen=seen,
+            vis_ids=jnp.full((b, max_visits), INVALID, jnp.int32),
+            vis_dists=jnp.full((b, max_visits), BIG, jnp.float32),
+            n_vis=jnp.zeros((b,), jnp.int32),
+            n_comps=jnp.where(starts >= 0, 1, 0).astype(jnp.int32),
+            n_hops=jnp.zeros((b,), jnp.int32),
         )
-    final_d = jnp.where(ret, out.beam_dists, BIG)
-    kk = min(k, l)  # the beam holds l entries; pad the tail with INVALID
-    top_d, top_i = lax.top_k(-final_d, kk)
-    topk_ids = jnp.where(
-        jnp.isfinite(-top_d),
-        jnp.take_along_axis(out.beam_ids, top_i, axis=1),
-        INVALID,
-    )
-    if kk < k:
-        topk_ids = jnp.pad(
-            topk_ids, ((0, 0), (0, k - kk)), constant_values=INVALID
+
+        def lane_active(s: _BLoop):
+            frontier = (
+                (s.beam_ids >= 0) & ~s.beam_exp & jnp.isfinite(s.beam_dists)
+            )
+            return jnp.any(frontier, axis=1) & (s.n_hops < max_visits)
+
+        def cond(s: _BLoop):
+            return jnp.any(lane_active(s))
+
+        h = resolved_hop_fused(cfg)
+        if h <= 0:
+            body = make_hop_body(state, cfg, queries, dist_fn, l=l,
+                                 max_visits=max_visits)
+        elif distance_fn is not None:
+            # a custom distance_fn has no kernel; fuse through the generic
+            # super-step so the override still sees every hop's distances
+            def body(s):
+                return superstep_reference(dist_fn, state, cfg, queries, s,
+                                           h=h, l=l, max_visits=max_visits)
+        elif use_q:
+            def body(s):
+                return backend.beam_superstep_q(state, cfg, queries, s, h=h,
+                                                l=l, max_visits=max_visits)
+        else:
+            def body(s):
+                return backend.beam_superstep(state, cfg, queries, s, h=h,
+                                              l=l, max_visits=max_visits)
+
+        out = lax.while_loop(cond, body, init)
+
+    with device_scope(SEARCH_SELECT):
+        # --- final top-k over each lane's beam, filtered to live vertices
+        ret = (returnable[clip_ids(out.beam_ids, cfg.n_cap)]
+               & (out.beam_ids >= 0))
+        if use_q:
+            # exact rescore (FreshDiskANN): re-rank the surviving beam
+            # against the full-precision table so the selection (and the
+            # reported distances) never carry quantization error; one
+            # (B, l) exact tile per query batch vs. the hops' many (B, R)
+            # quantized tiles
+            beam_d = backend.dists_to_ids_batched(
+                state, cfg, queries, jnp.where(ret, out.beam_ids, INVALID)
+            )
+            out = out._replace(
+                beam_dists=beam_d,
+                n_comps=(out.n_comps
+                         + jnp.sum(ret, axis=1).astype(jnp.int32)),
+            )
+        final_d = jnp.where(ret, out.beam_dists, BIG)
+        kk = min(k, l)  # the beam holds l entries; pad the tail with INVALID
+        top_d, top_i = lax.top_k(-final_d, kk)
+        topk_ids = jnp.where(
+            jnp.isfinite(-top_d),
+            jnp.take_along_axis(out.beam_ids, top_i, axis=1),
+            INVALID,
         )
-        top_d = jnp.pad(top_d, ((0, 0), (0, k - kk)), constant_values=-BIG)
-    topk_dists = -top_d
-    if use_q:
-        # recompute on exactly the returned (B, k) ids so topk_dists are
-        # BIT-equal to the caller-side f32 rescore oracle (same jitted
-        # call, same operand shapes => same reduction order)
-        topk_dists = backend.dists_to_ids_batched(
-            state, cfg, queries, topk_ids
-        )
+        if kk < k:
+            topk_ids = jnp.pad(
+                topk_ids, ((0, 0), (0, k - kk)), constant_values=INVALID
+            )
+            top_d = jnp.pad(top_d, ((0, 0), (0, k - kk)),
+                            constant_values=-BIG)
+        topk_dists = -top_d
+        if use_q:
+            # recompute on exactly the returned (B, k) ids so topk_dists are
+            # BIT-equal to the caller-side f32 rescore oracle (same jitted
+            # call, same operand shapes => same reduction order)
+            topk_dists = backend.dists_to_ids_batched(
+                state, cfg, queries, topk_ids
+            )
     return SearchResult(
         topk_ids=topk_ids,
         topk_dists=topk_dists,
