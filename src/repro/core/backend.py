@@ -139,8 +139,10 @@ class DistanceBackend:
         """f32[M] distances from ``q`` to rows (M, D).  No masking."""
         raise NotImplementedError
 
-    def pair_dists(self, cfg: ANNConfig, a_vecs, a_norms, b_vecs, b_norms):
-        """(A, B) distance matrix between two point sets.  No masking."""
+    def pair_dists(self, cfg: ANNConfig, a_vecs, a_norms, b_vecs, b_norms,
+                   precision=None):
+        """(A, B) distance matrix between two point sets.  No masking.
+        ``precision`` is the inner products' ``jax.lax.Precision``."""
         raise NotImplementedError
 
     def pair_dists_ids(self, state: GraphState, cfg: ANNConfig, a_ids, b_ids):
@@ -227,8 +229,10 @@ class JnpBackend(DistanceBackend):
     def dists_from_rows(self, cfg, q, q_norm, rows, row_norms):
         return _math.dists_from_rows(cfg.metric, q, q_norm, rows, row_norms)
 
-    def pair_dists(self, cfg, a_vecs, a_norms, b_vecs, b_norms):
-        return _math.pair_dists(cfg.metric, a_vecs, a_norms, b_vecs, b_norms)
+    def pair_dists(self, cfg, a_vecs, a_norms, b_vecs, b_norms,
+                   precision=None):
+        return _math.pair_dists(cfg.metric, a_vecs, a_norms, b_vecs, b_norms,
+                                precision)
 
     def brute_force_topk(self, state, cfg, queries, *, k):
         q_norms = (

@@ -13,11 +13,13 @@ splits each update into a *search phase* and a *write phase*:
             (``edges.append_edges``); inserts' own RobustPrunes run for the
             whole batch at once before the scan.
 
-The searches dominate update cost (the paper's Table 3 shows deletion time
-is search-bound), so batching them converts the serial update stream into
-one wide SPMD program.  Recall impact is bounded by the batch size (same
-argument as the paper's multi-threaded execution) and measured in
-benchmarks/perf_ann.py.
+Batching the searches converts the serial update stream into one wide
+SPMD program.  The write phase costs as much: on a v5e at R 64, an update
+call of 128 inserts and 128 deletes over 65,536 points spends about 30% in
+each search phase and 37% in the delete repair, most of that RobustPrune
+of full rows in its edge appends (PERF.md, section 5).  Recall impact is
+bounded by the batch size (same argument as the paper's multi-threaded
+execution) and measured in benchmarks/perf_ann.py.
 
 All distance math here (batched searches, top-c candidate matrices, prune)
 goes through the backend selected by ``cfg.backend`` (core/backend.py).

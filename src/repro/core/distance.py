@@ -40,9 +40,10 @@ def dists_to_ids(state: GraphState, cfg: ANNConfig, q, ids):
     return jnp.where(ids >= 0, d, BIG)
 
 
-def pair_dists(metric: str, a_vecs, a_norms, b_vecs, b_norms):
+def pair_dists(metric: str, a_vecs, a_norms, b_vecs, b_norms,
+               precision=None):
     """(A, B) distance matrix between two point sets (no masking)."""
-    prod = a_vecs @ b_vecs.T
+    prod = jnp.matmul(a_vecs, b_vecs.T, precision=precision)
     if metric == "l2":
         return a_norms[:, None] + b_norms[None, :] - 2.0 * prod
     return -prod

@@ -1,4 +1,14 @@
-"""Edge mutation helpers shared by insert / delete (Algorithms 2 and 5)."""
+"""Edge mutation helpers shared by insert / delete (Algorithms 2 and 5).
+
+``append_edges`` adds edges as Algorithm 2 lines 5-8 do: a row with room
+takes the edge, a full row is RobustPruned over its r entries plus the new
+one.  That prune is r + 1 wide, at most ``prune.BLOCK_MAX`` for every
+degree this index runs (R 32 and 64), so it takes RobustPrune's block path:
+one (r + 1)^2 distance block and a fixed forward scan per row, vmapped
+over a batch of rows, with no loop whose trip count depends on the data.
+Wider prunes (an insert's visited list, consolidation's splice) run
+RobustPrune's loop; see ``core/prune.py``.
+"""
 from __future__ import annotations
 
 import jax

@@ -1,4 +1,5 @@
 """RobustPrune vs numpy oracle + properties."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -6,6 +7,7 @@ from hyp_compat import given, settings, st
 
 from oracles import robust_prune_oracle
 from repro.core import ANNConfig, init_state, robust_prune
+from repro.core import prune as prune_mod
 from repro.core.types import INVALID
 
 
@@ -101,3 +103,140 @@ def test_prune_property_first_is_nearest(seed):
     out = np.asarray(robust_prune(state, cfg, jnp.asarray(p), jnp.asarray(cand)))
     d = ((vecs[cand] - p) ** 2).sum(1)
     assert out[0] == cand[np.argmin(d)]
+
+
+# -- the block path (C <= BLOCK_MAX) and the loop path, case by case -------
+
+def _basis_case(rng, r, dim):
+    """r + 1 candidates no one occludes (scaled basis vectors around p = 0),
+    so the degree cap alone stops the selection, at exactly r."""
+    c = r + 1
+    vecs = np.zeros((c, dim), np.float32)
+    vecs[np.arange(c), np.arange(c)] = 1.0 + 0.01 * rng.permutation(c)
+    return vecs, np.zeros(dim, np.float32), np.arange(c, dtype=np.int32), None
+
+
+def _cluster_case(rng, r, dim):
+    """A tight cluster far from p: the nearest candidate occludes the rest."""
+    c = r + 1
+    centre = np.full(dim, 10.0 / np.sqrt(dim), np.float32)
+    vecs = centre + 1e-2 * rng.normal(size=(c, dim)).astype(np.float32)
+    return vecs, np.zeros(dim, np.float32), np.arange(c, dtype=np.int32), None
+
+
+def _random_case(rng, r, dim, c=None, invalid=0.1):
+    c = r + 1 if c is None else c
+    n = 2 * c
+    vecs = rng.normal(size=(n, dim)).astype(np.float32)
+    cand = rng.choice(n, size=c, replace=False).astype(np.int32)
+    cand[rng.random(c) < invalid] = INVALID
+    return vecs, rng.normal(size=dim).astype(np.float32), cand, None
+
+
+def _twin_case(rng, r, dim):
+    """The same vector at two slots: a tie in d_p, the lower index first."""
+    vecs, p, cand, _ = _random_case(rng, r, dim, invalid=0.0)
+    vecs[cand[3]] = vecs[cand[1]]
+    vecs[cand[7]] = vecs[cand[5]]
+    return vecs, p, cand, None
+
+
+def _given_case(rng, r, dim):
+    """``cand_dists`` with inf entries (recomputed) and ties among the
+    finite ones (visited in index order)."""
+    vecs, p, cand, _ = _random_case(rng, r, dim)
+    dists = np.full(len(cand), np.inf, np.float32)
+    pick = rng.random(len(cand)) < 0.5
+    dists[pick] = rng.choice([0.5, 1.0, 2.0], size=int(pick.sum()))
+    return vecs, p, cand, dists
+
+
+def _invalid_case(rng, r, dim):
+    vecs, p, cand, _ = _random_case(rng, r, dim)
+    return vecs, p, np.full_like(cand, INVALID), None
+
+
+CASES = {
+    "random": _random_case, "cap": _basis_case, "one_occludes_all": _cluster_case,
+    "twins": _twin_case, "given_dists": _given_case, "all_invalid": _invalid_case,
+}
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+@pytest.mark.parametrize("alpha", [1.0, 1.2])
+@pytest.mark.parametrize("r", [8, 32, 64])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_block_prune_matches_oracle(case, r, alpha, metric):
+    """C = r + 1 (every edge append's width) takes the block path and gives
+    the oracle's ids in the oracle's order."""
+    rng = np.random.default_rng(
+        [r, int(alpha * 10), metric == "ip", sorted(CASES).index(case)])
+    dim = 80 if case == "cap" else 16
+    vecs, p, cand, dists = CASES[case](rng, r, dim)
+    if metric == "ip":
+        vecs = vecs / np.maximum(np.linalg.norm(vecs, axis=1, keepdims=True),
+                                 1e-6)
+    n = vecs.shape[0]
+    cfg = ANNConfig(dim=dim, n_cap=n, r=r, metric=metric, alpha=alpha)
+    assert len(cand) <= prune_mod.BLOCK_MAX
+    state = _mk_state(cfg, vecs)
+    got = np.asarray(robust_prune(
+        state, cfg, jnp.asarray(p), jnp.asarray(cand),
+        None if dists is None else jnp.asarray(dists)))
+    want = robust_prune_oracle(metric, alpha, r, p, cand, vecs,
+                               np.ones(n, bool), cand_dists=dists)
+    assert [int(x) for x in got if x >= 0] == want
+    assert (got[len(want):] == INVALID).all()
+    if case == "cap" and metric == "l2":
+        assert len(want) == r
+    if case == "one_occludes_all" and metric == "l2":
+        assert len(want) == 1
+
+
+@pytest.mark.parametrize("c", [129, 192])
+def test_loop_prune_matches_oracle(c):
+    """Wider candidate sets (the insert's visited list, consolidation's
+    splice) keep the loop and give the oracle's ids in order."""
+    rng = np.random.default_rng(c)
+    r, dim = 16, 16
+    vecs, p, cand, _ = _random_case(rng, r, dim, c=c)
+    n = vecs.shape[0]
+    cfg = ANNConfig(dim=dim, n_cap=n, r=r, alpha=1.2)
+    got = np.asarray(robust_prune(_mk_state(cfg, vecs), cfg, jnp.asarray(p),
+                                  jnp.asarray(cand)))
+    want = robust_prune_oracle("l2", 1.2, r, p, cand, vecs, np.ones(n, bool))
+    assert [int(x) for x in got if x >= 0] == want
+
+
+@pytest.mark.parametrize("c,loop", [
+    (33, False),           # an edge append at R 32
+    (65, False),           # an edge append at R 64
+    (128, False),          # the insert's visited list at L 64
+    (192, True),           # the insert's visited list at L 128
+    (64 + 64 * 64, True),  # consolidation's splice at R 64
+])
+def test_prune_path_follows_width(c, loop):
+    """The candidate width alone picks the path: the lowered program holds a
+    while loop above BLOCK_MAX and none at or below it."""
+    cfg = ANNConfig(dim=128, n_cap=1024, r=64, alpha=1.2)
+    state = jax.eval_shape(lambda: init_state(cfg))
+    text = robust_prune.lower(
+        state, cfg, jax.ShapeDtypeStruct((128,), jnp.float32),
+        jax.ShapeDtypeStruct((c,), jnp.int32),
+    ).as_text()
+    assert ("stablehlo.while" in text) == loop
+
+
+def test_block_prune_occlusion_product_at_full_precision():
+    """The block's (C, C) inner products ask for HIGHEST precision: on TPU a
+    default-precision product rounds its operands to bfloat16, where the
+    loop's matvec (a float32 multiply and sum there) does not."""
+    cfg = ANNConfig(dim=128, n_cap=1024, r=64, alpha=1.2)
+    state = jax.eval_shape(lambda: init_state(cfg))
+    text = robust_prune.lower(
+        state, cfg, jax.ShapeDtypeStruct((128,), jnp.float32),
+        jax.ShapeDtypeStruct((65,), jnp.int32),
+    ).as_text()
+    dots = [ln for ln in text.splitlines() if "stablehlo.dot_general" in ln
+            and "tensor<65x128xf32>, tensor<128x65xf32>" in ln]
+    assert dots and all("HIGHEST" in ln for ln in dots), dots
