@@ -54,7 +54,7 @@ def _bench_backend(name: str, state, cfg_base, ids, q, queries, k: int,
         "gather_us_per_call": gather_s * 1e6,
         "gather_dists_per_s": ids.shape[0] / gather_s,
         "brute_topk_us_per_call": topk_s * 1e6,
-        "brute_topk_dists_per_s": queries.shape[0] * state.vectors.shape[0]
+        "brute_topk_dists_per_s": queries.shape[0] * state.norms.shape[0]
         / topk_s,
     }
 
@@ -65,6 +65,7 @@ def run(out_path: str = "BENCH_backend.json",
     import numpy as np
 
     from repro.core import ANNConfig, init_state, make_dataset
+    from repro.core.types import write_vectors
 
     n = scale(2048, 65_536)
     dim = scale(64, 128)
@@ -73,7 +74,8 @@ def run(out_path: str = "BENCH_backend.json",
     cfg = ANNConfig(dim=dim, n_cap=n, r=r)
     state = init_state(cfg)
     state = state._replace(
-        vectors=jnp.asarray(data),
+        vectors=write_vectors(state.vectors, jnp.arange(n),
+                              jnp.asarray(data), dim),
         norms=jnp.sum(jnp.asarray(data) ** 2, axis=1),
         active=jnp.ones((n,), bool),
     )

@@ -44,13 +44,16 @@ def _make_state(n: int, dim: int, r: int, seed: int = 0):
     import numpy as np
 
     from repro.core import ANNConfig, init_state
+    from repro.core.types import write_vectors
 
     rng = np.random.default_rng(seed)
     data = rng.normal(size=(n, dim)).astype(np.float32)
     adj = rng.integers(0, n, size=(n, r)).astype(np.int32)
     cfg = ANNConfig(dim=dim, n_cap=n, r=r)
-    state = init_state(cfg)._replace(
-        vectors=jnp.asarray(data),
+    state = init_state(cfg)
+    state = state._replace(
+        vectors=write_vectors(state.vectors, jnp.arange(n), jnp.asarray(data),
+                              dim),
         norms=jnp.sum(jnp.asarray(data) ** 2, axis=1),
         adj=jnp.asarray(adj),
         active=jnp.ones((n,), bool),

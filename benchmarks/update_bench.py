@@ -50,7 +50,7 @@ def _make_istate(n: int, dim: int, r: int, n_free: int, seed: int = 0,
     import numpy as np
 
     from repro.core import ANNConfig, init_index_state
-    from repro.core.types import INVALID
+    from repro.core.types import INVALID, write_vectors
 
     rng = np.random.default_rng(seed)
     n_live = n - n_free
@@ -76,7 +76,8 @@ def _make_istate(n: int, dim: int, r: int, n_free: int, seed: int = 0,
     st = init_index_state(cfg, n * 2)
     st = st._replace(
         graph=st.graph._replace(
-            vectors=jnp.asarray(data),
+            vectors=write_vectors(st.graph.vectors, jnp.arange(n),
+                                  jnp.asarray(data), dim),
             norms=jnp.sum(jnp.asarray(data) ** 2, axis=1),
             adj=jnp.asarray(adj),
             active=jnp.asarray(active),

@@ -2,7 +2,12 @@
 
 ``backend`` selects the distance kernel engine (core/backend.py):
 ``"auto"`` (default) rides the Pallas kernels on TPU and pure jnp off-TPU;
-``"jnp"`` / ``"pallas"`` / ``"ref"`` force a specific engine.
+``"jnp"`` / ``"pallas"`` / ``"ref"`` force a specific engine.  Any ``dim``
+compiles for the chip: vectors are stored as ceil(dim / 128) rows of 128
+lanes (``core/types.py``); the gather kernel reads one-row vectors (dim <=
+128), and wider ones take XLA's row gather.  ``origin_start`` enters every
+search from a frozen point at the zero vector (``core/types.py``), as a
+768-d inner-product deployment of clustered unit vectors needs.
 """
 from __future__ import annotations
 

@@ -271,6 +271,11 @@ class FreshDiskANNPolicy(UpdatePolicy):
     (Alg 4) past the threshold."""
 
     def delete_many(self, graph, cfg, ps, *, sequential):
+        if cfg.origin_start:
+            # its consolidation releases every tombstone, the frozen start's
+            # slot among them
+            raise ValueError("the fresh policy does not take "
+                             "ANNConfig.origin_start")
         # lazy delete is a trivially cheap mask flip; the serial scan IS the
         # batched formulation
         return lazy_delete_many(graph, cfg, ps)

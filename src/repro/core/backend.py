@@ -43,7 +43,15 @@ import jax
 import jax.numpy as jnp
 
 from . import distance as _math
-from .types import ANNConfig, GraphState, clip_ids
+from .spans import GATHER, device_scope
+from .types import (
+    ROW_LANES,
+    ANNConfig,
+    GraphState,
+    clip_ids,
+    read_vectors,
+    row_blocks,
+)
 
 BIG = _math.BIG
 
@@ -151,8 +159,8 @@ class DistanceBackend:
         sb = clip_ids(b_ids, cfg.n_cap)
         d = self.pair_dists(
             cfg,
-            state.vectors[sa], state.norms[sa],
-            state.vectors[sb], state.norms[sb],
+            read_vectors(state.vectors, sa, cfg.dim), state.norms[sa],
+            read_vectors(state.vectors, sb, cfg.dim), state.norms[sb],
         )
         invalid = (a_ids[:, None] < 0) | (b_ids[None, :] < 0)
         return jnp.where(invalid, BIG, d)
@@ -240,10 +248,57 @@ class JnpBackend(DistanceBackend):
             if cfg.metric == "l2"
             else jnp.zeros((queries.shape[0],), jnp.float32)
         )
-        d = self.pair_dists(cfg, queries, q_norms, state.vectors, state.norms)
+        if row_blocks(cfg.dim) > 1:
+            return _chunked_topk(self, state, cfg, queries, q_norms, k)
+        d = self.pair_dists(cfg, _in_lanes(cfg, queries), q_norms,
+                            state.vectors, state.norms)
         d = jnp.where(state.active[None, :], d, BIG)
         neg, idx = jax.lax.top_k(-d, k)
         return jnp.where(jnp.isfinite(neg), idx, -1), -neg
+
+
+def _in_lanes(cfg: ANNConfig, queries):
+    """``queries`` (..., dim) zero-padded to a table row's ``ROW_LANES``
+    lanes, so they score a one-row-per-vector table as it is (zero lanes
+    add 0)."""
+    if cfg.dim == ROW_LANES:
+        return queries
+    pad = [(0, 0)] * (queries.ndim - 1) + [(0, ROW_LANES - cfg.dim)]
+    return jnp.pad(queries, pad)
+
+
+SCAN_CHUNK = 4096  # slots per step of the exact scan over multi-row tables
+
+
+def _chunked_topk(be, state, cfg, queries, q_norms, k):
+    """The exact scan over a table of several rows per vector: chunks of
+    ``SCAN_CHUNK`` slots read through ``read_vectors``, each merged into a
+    running top-k, so no step holds more than one chunk of vectors (the
+    table as ``(n_cap, dim)`` would be a relayout copy of all of it)."""
+    chunk = min(SCAN_CHUNK, cfg.n_cap)
+    n_chunks = -(-cfg.n_cap // chunk)
+    q = queries.shape[0]
+
+    def step(carry, c):
+        top_d, top_i = carry
+        ids = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
+        safe = clip_ids(ids, cfg.n_cap)
+        d = be.pair_dists(cfg, queries, q_norms,
+                          read_vectors(state.vectors, safe, cfg.dim),
+                          state.norms[safe])
+        live = (ids < cfg.n_cap) & state.active[safe]
+        d = jnp.where(live[None, :], d, BIG)
+        all_d = jnp.concatenate([top_d, d], axis=1)
+        all_i = jnp.concatenate(
+            [top_i, jnp.broadcast_to(ids, (q, chunk))], axis=1)
+        neg, pos = jax.lax.top_k(-all_d, k)
+        return (-neg, jnp.take_along_axis(all_i, pos, axis=1)), None
+
+    init = (jnp.full((q, k), BIG, jnp.float32),
+            jnp.full((q, k), -1, jnp.int32))
+    (top_d, top_i), _ = jax.lax.scan(step, init,
+                                     jnp.arange(n_chunks, dtype=jnp.int32))
+    return jnp.where(jnp.isfinite(top_d), top_i, -1), top_d
 
 
 # ---------------------------------------------------------------------------
@@ -261,6 +316,12 @@ class PallasBackend(JnpBackend):
     tile-local helpers (``dists_from_rows`` / ``pair_dists``) operate on
     rows the caller already gathered, so they inherit the jnp math — there
     is no HBM traffic left for a kernel to save.
+
+    The kernels take one table row a vector (``dim <= 128``, the query
+    zero-padded to the row's lanes).  Wider vectors, several rows each,
+    take the inherited XLA row gather, chosen from the static ``cfg.dim``
+    (``kernels/gather_distance.py`` says why).  Either way the gather and
+    its distances run under the ``ann.gather`` scope.
     """
 
     interpret = None  # None => auto: interpret off-TPU, Mosaic on TPU
@@ -284,23 +345,30 @@ class PallasBackend(JnpBackend):
     def dists_to_ids(self, state, cfg, q, ids):
         from ..kernels import ops
 
-        return ops.gather_distances(
-            ids, q, state.vectors, norms=state.norms, metric=cfg.metric,
-            interpret=self.interpret,
-        )
+        with device_scope(GATHER):
+            if row_blocks(cfg.dim) > 1:
+                return super().dists_to_ids(state, cfg, q, ids)
+            return ops.gather_distances(
+                ids, _in_lanes(cfg, q), state.vectors, norms=state.norms,
+                metric=cfg.metric, interpret=self.interpret,
+            )
 
     def dists_to_ids_batched(self, state, cfg, queries, ids):
         from ..kernels import ops
 
-        return ops.gather_distances_batched(
-            ids, queries, state.vectors, norms=state.norms,
-            metric=cfg.metric, interpret=self.interpret,
-        )
+        with device_scope(GATHER):
+            if row_blocks(cfg.dim) > 1:
+                return super().dists_to_ids_batched(state, cfg, queries, ids)
+            return ops.gather_distances_batched(
+                ids, _in_lanes(cfg, queries), state.vectors,
+                norms=state.norms, metric=cfg.metric,
+                interpret=self.interpret,
+            )
 
     def beam_superstep(self, state, cfg, queries, carry, *, h, l,
                        max_visits):
         from . import bitset
-        from .types import navigable
+        from .types import navigable, recorded
         from ..kernels import ops
 
         self._refuse_on_mosaic(
@@ -308,12 +376,16 @@ class PallasBackend(JnpBackend):
             "gathers, in-kernel sort, an (1, n_cap) VMEM norms block)",
             "hop_fused",
         )
+        if row_blocks(cfg.dim) > 1:
+            # one table row a vector, as the gather kernel
+            return super().beam_superstep(state, cfg, queries, carry, h=h,
+                                          l=l, max_visits=max_visits)
         # cheap O(n_cap) elementwise packs of the loop-invariant masks;
         # dwarfed by the O(B * R * D) distance math of the h hops
         nav_words = bitset.pack_bits(navigable(state))
-        ret_words = bitset.pack_bits(state.active)
+        ret_words = bitset.pack_bits(recorded(state, cfg))
         out = ops.beam_hop(
-            queries, carry.beam_ids, carry.beam_dists,
+            _in_lanes(cfg, queries), carry.beam_ids, carry.beam_dists,
             carry.beam_exp.astype(jnp.int32), carry.seen, carry.vis_ids,
             carry.vis_dists, carry.n_vis, carry.n_comps, carry.n_hops,
             state.adj, state.vectors, state.norms, nav_words, ret_words,
@@ -339,7 +411,7 @@ class PallasBackend(JnpBackend):
     def beam_superstep_q(self, state, cfg, queries, carry, *, h, l,
                          max_visits):
         from . import bitset
-        from .types import navigable
+        from .types import navigable, recorded
         from ..kernels import ops
 
         self._refuse_on_mosaic(
@@ -347,7 +419,7 @@ class PallasBackend(JnpBackend):
             "hop_fused",
         )
         nav_words = bitset.pack_bits(navigable(state))
-        ret_words = bitset.pack_bits(state.active)
+        ret_words = bitset.pack_bits(recorded(state, cfg))
         out = ops.beam_hop_q(
             queries, carry.beam_ids, carry.beam_dists,
             carry.beam_exp.astype(jnp.int32), carry.seen, carry.vis_ids,
@@ -363,9 +435,13 @@ class PallasBackend(JnpBackend):
     def brute_force_topk(self, state, cfg, queries, *, k):
         from ..kernels import ops
 
+        if row_blocks(cfg.dim) > 1:
+            # the scorer streams (tile, D) blocks of a one-row-per-vector
+            # table; wider vectors take the XLA scan
+            return super().brute_force_topk(state, cfg, queries, k=k)
         return self._biased_topk(state, lambda bias: ops.topk_search(
-            queries, state.vectors, state.norms, k=k, metric=cfg.metric,
-            bias=bias, interpret=self.interpret,
+            _in_lanes(cfg, queries), state.vectors, state.norms, k=k,
+            metric=cfg.metric, bias=bias, interpret=self.interpret,
         ))
 
 
@@ -383,7 +459,7 @@ class RefBackend(JnpBackend):
         from ..kernels import ref
 
         return ref.gather_distance_ref(
-            ids, q, state.vectors, metric=cfg.metric
+            ids, q, _plain_table(state, cfg), metric=cfg.metric
         )
 
     # dists_to_ids_batched: the inherited vmap default IS the batched ref
@@ -401,8 +477,15 @@ class RefBackend(JnpBackend):
         from ..kernels import ref
 
         return self._biased_topk(state, lambda bias: ref.topk_score_ref(
-            queries, state.vectors, state.norms, bias, k=k, metric=cfg.metric,
+            queries, _plain_table(state, cfg), state.norms, bias, k=k,
+            metric=cfg.metric,
         ))
+
+
+def _plain_table(state: GraphState, cfg: ANNConfig):
+    """The whole table as ``(n_cap, dim)``, for the oracles (a copy of it:
+    never on the engines' paths)."""
+    return read_vectors(state.vectors, jnp.arange(cfg.n_cap), cfg.dim)
 
 
 __all__ = [

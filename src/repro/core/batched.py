@@ -46,7 +46,14 @@ from .spans import (
     INSERT_SEARCH,
     device_scope,
 )
-from .types import INVALID, ANNConfig, GraphState, clip_ids
+from .types import (
+    INVALID,
+    ANNConfig,
+    GraphState,
+    clip_ids,
+    read_vectors,
+    write_vectors,
+)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -81,7 +88,8 @@ def insert_many_batched(state: GraphState, cfg: ANNConfig, xs: jax.Array,
         # which write wins
         write_idx = jnp.where(ok, sslots, cfg.n_cap)
         state = state._replace(
-            vectors=state.vectors.at[write_idx].set(xs_f, mode="drop"),
+            vectors=write_vectors(state.vectors, write_idx, xs_f, cfg.dim,
+                                  mode="drop"),
             norms=state.norms.at[write_idx].set(
                 jnp.sum(xs_f * xs_f, axis=1), mode="drop"
             ),
@@ -146,7 +154,7 @@ def ip_delete_many_batched(state: GraphState, cfg: ANNConfig, ps: jax.Array):
         # phase 1: one shared-hop-loop batched search from every deleted
         # point (invalid lanes — INVALID or non-active slots — are dead from
         # hop 0)
-        x_ps = state.vectors[sps]
+        x_ps = read_vectors(state.vectors, sps, cfg.dim)
         res = batched_greedy_search(state, cfg, x_ps, k=cfg.k_delete,
                                     l=cfg.l_delete, valid=valid)
         vis_b = jnp.where(res.visited_ids == ps[:, None], INVALID,

@@ -27,6 +27,7 @@ from .types import (
     GraphState,
     clip_ids,
     compact_row,
+    read_vectors,
 )
 
 
@@ -126,7 +127,8 @@ def _consolidate_rows(state: GraphState, cfg: ANNConfig, nodes):
         cand, _ = _splice_candidates(state, cfg, node)
         # Alg 4 prunes the spliced candidate set back to <= r.
         return robust_prune(
-            state, cfg, state.vectors[node], cand, p_id=node
+            state, cfg, read_vectors(state.vectors, node, cfg.dim), cand,
+            p_id=node
         )
 
     return jax.vmap(one)(nodes)

@@ -34,7 +34,7 @@ from .backend import BIG, resolve_backend
 from .edges import append_edges, remove_target_everywhere, remove_target_rows
 from .search import greedy_search
 from .spans import DELETE_REPAIR, DELETE_SEARCH, device_scope
-from .types import INVALID, ANNConfig, GraphState, clip_ids
+from .types import INVALID, ANNConfig, GraphState, clip_ids, read_vectors
 
 
 class DeleteStats(NamedTuple):
@@ -63,8 +63,8 @@ def ip_delete(state: GraphState, cfg: ANNConfig, p: jax.Array):
 
     def do_delete(st: GraphState):
         with device_scope(DELETE_SEARCH):
-            res = greedy_search(st, cfg, st.vectors[sp], k=cfg.k_delete,
-                                l=cfg.l_delete)
+            res = greedy_search(st, cfg, read_vectors(st.vectors, sp, cfg.dim),
+                                k=cfg.k_delete, l=cfg.l_delete)
         with device_scope(DELETE_REPAIR):
             return repair(st, res)
 

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from .types import ANNConfig, GraphState, clip_ids
+from .types import ANNConfig, GraphState, clip_ids, read_vectors
 
 BIG = jnp.inf
 
@@ -34,7 +34,7 @@ def dists_from_rows(metric: str, q, q_norm, rows, row_norms):
 def dists_to_ids(state: GraphState, cfg: ANNConfig, q, ids):
     """f32[M] distances from q to slots ``ids``; inf where id is INVALID."""
     safe = clip_ids(ids, cfg.n_cap)
-    rows = state.vectors[safe]
+    rows = read_vectors(state.vectors, safe, cfg.dim)
     q_norm = jnp.dot(q, q) if cfg.metric == "l2" else 0.0
     d = dists_from_rows(cfg.metric, q, q_norm, rows, state.norms[safe])
     return jnp.where(ids >= 0, d, BIG)

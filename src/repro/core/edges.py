@@ -23,6 +23,7 @@ from .types import (
     GraphState,
     clip_ids,
     compact_row,
+    read_vectors,
     row_contains,
     row_count,
 )
@@ -49,7 +50,8 @@ def _appended_row(state: GraphState, cfg: ANNConfig, v, u, row):
     # broadcasts every operand it closes over (the vector table) per lane
     appended = row.at[cnt].set(u, mode="drop")
     cand = jnp.concatenate([row, jnp.asarray(u, jnp.int32)[None]])
-    pruned = robust_prune(state, cfg, state.vectors[sv], cand, p_id=v)
+    pruned = robust_prune(state, cfg, read_vectors(state.vectors, sv, cfg.dim),
+                          cand, p_id=v)
     return jnp.where(skip, row, jnp.where(cnt < cfg.r, appended, pruned))
 
 

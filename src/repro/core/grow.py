@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .quant import QuantStore
-from .types import INVALID, ANNConfig, GraphState, IndexState
+from .types import INVALID, ANNConfig, GraphState, IndexState, init_vectors
 
 # Grow when (live + incoming) would exceed this fraction of capacity: the
 # graph needs free slots for in-flight quarantined/tombstoned rows, and
@@ -81,7 +81,8 @@ def _grow_graph(g: GraphState, cfg: ANNConfig, new_cap: int) -> GraphState:
             qnorms=pad_rows(quant.qnorms, 0.0),
         )
     return g._replace(
-        vectors=pad_rows(g.vectors, 0),
+        vectors=jnp.concatenate(
+            [g.vectors, init_vectors(extra, cfg.dim, g.vectors.dtype)]),
         norms=pad_rows(g.norms, 0.0),
         adj=pad_rows(g.adj, INVALID),
         active=pad_rows(g.active, False),

@@ -28,7 +28,15 @@ from jax import lax
 from .index import EvalCounters, OpCounters
 from .prune import robust_prune
 from .search import greedy_search, search_batch
-from .types import INVALID, ANNConfig, GraphState, clip_ids
+from .types import (
+    INVALID,
+    ANNConfig,
+    GraphState,
+    clip_ids,
+    init_vectors,
+    read_vectors,
+    write_vectors,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,7 +64,7 @@ class HNSWConfig:
 
 
 class HNSWState(NamedTuple):
-    vectors: jax.Array    # f32[n_cap, dim]
+    vectors: jax.Array    # the vector table (core/types.py: 128-lane rows)
     norms: jax.Array      # f32[n_cap]
     adj0: jax.Array       # i32[n_cap, m0]
     adj_up: jax.Array     # i32[max_level, n_cap, m]
@@ -74,7 +82,7 @@ class HNSWState(NamedTuple):
 def init_hnsw(cfg: HNSWConfig) -> HNSWState:
     n = cfg.n_cap
     return HNSWState(
-        vectors=jnp.zeros((n, cfg.dim), jnp.float32),
+        vectors=init_vectors(n, cfg.dim),
         norms=jnp.zeros((n,), jnp.float32),
         adj0=jnp.full((n, cfg.m0), INVALID, jnp.int32),
         adj_up=jnp.full((cfg.max_level, n, cfg.m), INVALID, jnp.int32),
@@ -143,7 +151,8 @@ def _link(st: HNSWState, cfg: HNSWConfig, level: int, slot, x,
         def shrink(a):
             cand = jnp.concatenate([row, jnp.asarray(slot, jnp.int32)[None]])
             new_row = robust_prune(
-                view._replace(adj=a), lcfg, st.vectors[sv], cand, p_id=v
+                view._replace(adj=a), lcfg,
+                read_vectors(st.vectors, sv, cfg.dim), cand, p_id=v
             )
             return a.at[sv].set(new_row)
 
@@ -162,7 +171,7 @@ def _insert_at_levels(st: HNSWState, cfg: HNSWConfig, x, slot,
     x = x.astype(jnp.float32)
     sslot = clip_ids(slot, cfg.n_cap)
     st = st._replace(
-        vectors=st.vectors.at[sslot].set(x),
+        vectors=write_vectors(st.vectors, sslot, x, cfg.dim),
         norms=st.norms.at[sslot].set(jnp.dot(x, x)),
         level=st.level.at[sslot].set(node_level),
         active=st.active.at[sslot].set(True),
@@ -201,7 +210,9 @@ def _repair_replaced(st: HNSWState, cfg: HNSWConfig, p) -> HNSWState:
             cand = jnp.concatenate([zrow, flat])
             cand = jnp.where(cand == p, INVALID, cand)
             return robust_prune(
-                view, lcfg, st.vectors[clip_ids(z, cfg.n_cap)], cand, p_id=z
+                view, lcfg,
+                read_vectors(st.vectors, clip_ids(z, cfg.n_cap), cfg.dim),
+                cand, p_id=z
             )
 
         new_rows = jax.vmap(fix_one)(row)

@@ -13,7 +13,7 @@ from .prune import robust_prune
 from .quant import quant_write_rows
 from .search import greedy_search
 from .spans import INSERT_LINK, INSERT_SEARCH, device_scope
-from .types import INVALID, ANNConfig, GraphState, clip_ids
+from .types import INVALID, ANNConfig, GraphState, clip_ids, write_vectors
 
 
 class InsertStats(NamedTuple):
@@ -36,7 +36,7 @@ def insert(state: GraphState, cfg: ANNConfig, x: jax.Array):
 
     def do_insert(st: GraphState):
         st = st._replace(
-            vectors=st.vectors.at[sslot].set(x),
+            vectors=write_vectors(st.vectors, sslot, x, cfg.dim),
             norms=st.norms.at[sslot].set(
                 jnp.dot(x, x).astype(jnp.float32)
             ),

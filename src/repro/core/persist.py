@@ -49,21 +49,29 @@ from ..checkpoint.manager import CheckpointManager, CheckpointMismatchError
 from ..ft.supervisor import SimulatedFailure
 from .api import SegmentPlan, segment_step
 from .grow import grow_index
-from .types import ANNConfig, IndexState, init_index_state
+from .types import ROW_LANES, ANNConfig, IndexState, init_index_state
 
 # Bumped whenever the IndexState pytree layout changes incompatibly; a
 # restore of a foreign schema is a typed error, not a shape crash mid-jit.
-SCHEMA_VERSION = 1
+# Schema 2 stores ``GraphState.vectors`` as rows of 128 lanes
+# (``core/types.py``).  A schema-1 table is the plain ``(n_cap, dim)``: at
+# dim 128 those are the same bytes, so it restores as it is; at any other
+# dim it is refused.
+SCHEMA_VERSION = 2
+PLAIN_TABLE_SCHEMA = 1
 
 # Config fields that must match bit-for-bit between writer and reader: they
-# size the state tensors (dim, r), change distance semantics (metric) or the
-# pytree structure (quantized).  Beam widths / thresholds are serving knobs —
-# they may differ across a restore and are recorded but not enforced.
+# size the state tensors (dim, r), change distance semantics (metric), the
+# pytree structure (quantized) or what slot 0 holds (origin_start).  Beam
+# widths / thresholds are serving knobs — they may differ across a restore
+# and are recorded but not enforced.
 # ``n_cap`` is validated separately: online growth (core/grow.py) walks
 # capacities through power-of-two buckets, so a checkpoint restores into any
 # bucket >= the one it was written under (the state is grown after load);
 # only a SHRINK is a mismatch.
-CFG_CRITICAL = ("dim", "r", "metric", "quantized")
+CFG_CRITICAL = ("dim", "r", "metric", "quantized", "origin_start")
+# the value of a critical field in a manifest written before it existed
+CFG_BEFORE = {"origin_start": False}
 
 
 def _index_meta(state: IndexState, cfg: ANNConfig, policy: str) -> dict:
@@ -122,17 +130,19 @@ def validate_index_manifest(manifest: dict, cfg: ANNConfig,
             "checkpoint does not hold an IndexState (no index metadata in "
             "the manifest — was it written by save_index?)"
         )
-    if meta.get("schema") != SCHEMA_VERSION:
+    schema = meta.get("schema")
+    plain_same = schema == PLAIN_TABLE_SCHEMA and cfg.dim == ROW_LANES
+    if schema != SCHEMA_VERSION and not plain_same:
         raise CheckpointMismatchError(
-            f"checkpoint schema {meta.get('schema')!r} != supported "
-            f"{SCHEMA_VERSION}"
+            f"checkpoint schema {schema!r} != supported {SCHEMA_VERSION} "
+            f"(schema {PLAIN_TABLE_SCHEMA} restores only at dim {ROW_LANES})"
         )
     saved = meta.get("config", {})
     mine = dataclasses.asdict(cfg)
     drift = {
-        k: (saved.get(k), mine[k])
+        k: (saved.get(k, CFG_BEFORE.get(k)), mine[k])
         for k in CFG_CRITICAL
-        if saved.get(k) != mine[k]
+        if saved.get(k, CFG_BEFORE.get(k)) != mine[k]
     }
     if drift:
         raise CheckpointMismatchError(

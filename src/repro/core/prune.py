@@ -22,6 +22,16 @@ one of two ways, chosen by that static width C:
 Both select exactly Algorithm 3's rows in its emission order; they differ
 only where a float32 distance computed by matmul rather than matvec falls
 on the other side of an occlusion tie.
+
+The occlusion test ``alpha * d(u, v) <= d(u, p)`` needs a non-negative
+distance for alpha > 1 to mean "keep more".  Under ``ip`` the distance is
+the negated inner product, on which alpha > 1 prunes *harder*: one kept
+neighbour would drop every candidate whose product with it is above
+``<u, p> / alpha``, i.e. a whole cluster of near-equal products.  So for
+``ip`` the test compares squared euclidean distances,
+``|u|^2 + |v|^2 + 2 d_ip(u, v)`` from the cached norms (for unit vectors
+``2 + 2 d_ip``, a monotone form of the ip distance), and for ``l2`` the
+distances themselves.  Candidates are still visited in ip order.
 """
 from __future__ import annotations
 
@@ -34,7 +44,14 @@ from jax import lax
 
 from .backend import BIG, resolve_backend
 from .spans import PRUNE, device_scope
-from .types import INVALID, ANNConfig, GraphState, clip_ids, mask_duplicates
+from .types import (
+    INVALID,
+    ANNConfig,
+    GraphState,
+    clip_ids,
+    mask_duplicates,
+    read_vectors,
+)
 
 BLOCK_MAX = 128  # widest candidate set pruned as one (C, C) distance block
 
@@ -67,7 +84,7 @@ def robust_prune(
     safe = clip_ids(ids, cfg.n_cap)
 
     be = resolve_backend(cfg)
-    cand_vecs = state.vectors[safe]          # (C, D)
+    cand_vecs = read_vectors(state.vectors, safe, cfg.dim)   # (C, D)
     cand_norms = state.norms[safe]           # (C,)  cached per-slot norms
     p_norm = be.query_norm(cfg, p_vec)
     d_p = be.dists_from_rows(cfg, p_vec, p_norm, cand_vecs, cand_norms)
@@ -75,11 +92,19 @@ def robust_prune(
         d_p = jnp.where(jnp.isfinite(cand_dists), cand_dists, d_p)
     d_p = jnp.where(ids >= 0, d_p, BIG)
 
+    d_occ = d_p                  # d(u, p) as the occlusion test takes it
+    if cfg.metric == "ip":
+        d_occ = _euclidean(d_p, cand_norms, jnp.dot(p_vec, p_vec))
     select = _select_block if ids.shape[0] <= BLOCK_MAX else _select_loop
-    return select(cfg, ids, cand_vecs, cand_norms, d_p)
+    return select(cfg, ids, cand_vecs, cand_norms, d_p, d_occ)
 
 
-def _select_block(cfg: ANNConfig, ids, cand_vecs, cand_norms, d_p):
+def _euclidean(d_ip, a_norms, b_norms):
+    """ip distances as squared euclidean ones, from the squared norms."""
+    return a_norms + b_norms + 2.0 * d_ip
+
+
+def _select_block(cfg: ANNConfig, ids, cand_vecs, cand_norms, d_p, d_occ):
     """Greedy selection as one distance block and a forward scan."""
     be = resolve_backend(cfg)
     c = ids.shape[0]
@@ -88,10 +113,11 @@ def _select_block(cfg: ANNConfig, ids, cand_vecs, cand_norms, d_p):
     # full float32: on TPU the loop's matvec runs as a float32 multiply and
     # sum, where a (C, C) product at default precision would round its
     # operands to bfloat16 and flip about half the rows' decisions.
-    occ = cfg.alpha * be.pair_dists(cfg, cand_vecs, cand_norms, cand_vecs,
-                                    cand_norms,
-                                    precision=lax.Precision.HIGHEST)
-    occ = occ <= d_p[None, :]
+    occ = be.pair_dists(cfg, cand_vecs, cand_norms, cand_vecs, cand_norms,
+                        precision=lax.Precision.HIGHEST)
+    if cfg.metric == "ip":
+        occ = _euclidean(occ, cand_norms[:, None], cand_norms[None, :])
+    occ = cfg.alpha * occ <= d_occ[None, :]
     # The loop's visiting order: by distance to p, ties to the lower index.
     # perm[i, j]: candidate j is visited i-th.  Ranks by comparison and
     # permutations by one-hot products, not a sort and gathers, which cost
@@ -120,7 +146,7 @@ def _select_block(cfg: ANNConfig, ids, cand_vecs, cand_norms, d_p):
     return jnp.max(jnp.where(hit, ids[None, :], INVALID), axis=1)
 
 
-def _select_loop(cfg: ANNConfig, ids, cand_vecs, cand_norms, d_p):
+def _select_loop(cfg: ANNConfig, ids, cand_vecs, cand_norms, d_p, d_occ):
     """Greedy selection as a loop of argmin steps (wide candidate sets)."""
     be = resolve_backend(cfg)
     alive = ids >= 0
@@ -137,7 +163,9 @@ def _select_loop(cfg: ANNConfig, ids, cand_vecs, cand_norms, d_p):
         v_vec = cand_vecs[j]
         v_norm = cand_norms[j]
         d_v = be.dists_from_rows(cfg, v_vec, v_norm, cand_vecs, cand_norms)
-        keep = cfg.alpha * d_v > d_p
+        if cfg.metric == "ip":
+            d_v = _euclidean(d_v, v_norm, cand_norms)
+        keep = cfg.alpha * d_v > d_occ
         alive = alive & jnp.where(ok, keep, True)
         alive = alive.at[j].set(False)
         return alive, out, n_out

@@ -4,6 +4,8 @@ A runbook is a dataset plus a sequence of steps; each step inserts and/or
 deletes dataset points.  Datasets are synthetic stand-ins for MSTuring
 (D=100, L2) and Wikipedia-Cohere (D=768, inner product): mixtures of
 Gaussians so that the Clustered runbook's k-means structure is non-trivial.
+Any width runs on the chip: the vector table holds ceil(D / 128) rows of 128
+lanes per point (``core/types.py``), one padded row at D=100, six at D=768.
 """
 from __future__ import annotations
 

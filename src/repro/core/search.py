@@ -23,7 +23,14 @@ from jax import lax
 
 from .backend import BIG, resolve_backend
 from .spans import SEARCH_DISPATCH, SEARCH_PAD, host_span
-from .types import INVALID, ANNConfig, GraphState, clip_ids, navigable
+from .types import (
+    INVALID,
+    ANNConfig,
+    GraphState,
+    clip_ids,
+    navigable,
+    recorded,
+)
 
 
 class SearchResult(NamedTuple):
@@ -74,6 +81,7 @@ def greedy_search(
     dist_fn = distance_fn or resolve_backend(cfg).dists_to_ids
     nav = navigable(state)
     returnable = state.active
+    visit = recorded(state, cfg)
 
     start = state.start
     d0 = dist_fn(state, cfg, q, start[None])[0]
@@ -113,11 +121,11 @@ def greedy_search(
         dv = s.beam_dists[i]
         beam_exp = s.beam_exp.at[i].set(True)
 
-        # --- record in visited list (only live/returnable vertices) ---------
-        # The write is conditional on returnability: a tombstoned pop must not
+        # --- record in visited list (only recorded vertices) ----------------
+        # The write is conditional on ``recorded``: a tombstoned pop must not
         # transiently occupy the slot a later live pop will claim (an
         # out-of-bounds index drops the write entirely).
-        v_ret = returnable[clip_ids(v, cfg.n_cap)]
+        v_ret = visit[clip_ids(v, cfg.n_cap)]
         slot = jnp.where(v_ret, s.n_vis, jnp.int32(max_visits))
         vis_ids = s.vis_ids.at[slot].set(v, mode="drop")
         vis_dists = s.vis_dists.at[slot].set(dv, mode="drop")

@@ -64,7 +64,14 @@ from .backend import BIG, resolve_backend
 from .search import SearchResult
 from .spans import (SEARCH_HOPS, SEARCH_SELECT, TRACE_COUNTER,
                     device_scope)
-from .types import INVALID, ANNConfig, GraphState, clip_ids, navigable
+from .types import (
+    INVALID,
+    ANNConfig,
+    GraphState,
+    clip_ids,
+    navigable,
+    recorded,
+)
 
 
 # Hops per super-step the fused-engine benchmarks and parity tests pin
@@ -139,7 +146,7 @@ def make_hop_body(state: GraphState, cfg: ANNConfig, queries: jax.Array,
     the stable sort-merge against all-inf neighbours returns the beam
     unchanged — which is what makes hop grouping traversal-neutral."""
     nav = navigable(state)
-    returnable = state.active
+    visit = recorded(state, cfg)
     b = queries.shape[0]
     bidx = jnp.arange(b)
 
@@ -161,9 +168,9 @@ def make_hop_body(state: GraphState, cfg: ANNConfig, queries: jax.Array,
         dv = s.beam_dists[bidx, i]
         beam_exp = s.beam_exp.at[bidx, i].set(s.beam_exp[bidx, i] | active)
 
-        # --- record in visited list (live/returnable pops of active lanes) --
+        # --- record in visited list (recorded pops of active lanes) --------
         sv = clip_ids(v, cfg.n_cap)
-        write = active & returnable[sv]
+        write = active & visit[sv]
         slot = jnp.where(write, s.n_vis, max_visits)   # OOB => dropped write
         vis_ids = s.vis_ids.at[bidx, slot].set(v, mode="drop")
         vis_dists = s.vis_dists.at[bidx, slot].set(dv, mode="drop")

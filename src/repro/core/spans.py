@@ -10,7 +10,10 @@ Two kinds of span, both prefixed ``ann.``:
     Every operation of ``apply_segment`` lies under exactly one of
     ``UPDATE_PHASES``; ``EDGES_APPEND`` and ``PRUNE`` nest beneath them,
     and the search program's ``SEARCH_HOPS`` / ``SEARCH_SELECT`` nest
-    beneath a phase when an update runs the search;
+    beneath a phase when an update runs the search.  ``VECTOR_ROWS`` (the
+    row gathers of ``types.read_vectors``) and ``GATHER`` (the gather
+    kernel's call in ``backend.PallasBackend``) nest wherever their code
+    runs;
   * host spans (``host_span``, a ``jax.profiler.TraceAnnotation``): one
     inactive ``TraceMe`` each when no trace is running.  They mark the
     host steps of the query front door (``core/api.py::search``).
@@ -39,8 +42,10 @@ EDGES_APPEND = "ann.edges.append"     # edges.append_edges
 PRUNE = "ann.prune"                   # prune.robust_prune
 SEARCH_HOPS = "ann.search.hops"       # the shared hop loop, gathers included
 SEARCH_SELECT = "ann.search.select"   # the final top-k and rescore
+VECTOR_ROWS = "ann.vectors.rows"      # types.read_vectors: XLA row gathers
+GATHER = "ann.gather"                 # the Pallas gather-distance kernel
 DEVICE_SCOPES = UPDATE_PHASES + (EDGES_APPEND, PRUNE, SEARCH_HOPS,
-                                 SEARCH_SELECT)
+                                 SEARCH_SELECT, VECTOR_ROWS, GATHER)
 
 # ---- host spans: the query front door, a parent and its children in order -
 SEARCH = "ann.search"

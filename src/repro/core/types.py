@@ -4,7 +4,10 @@ The paper's CPU implementation stores the graph as per-node ``Vec<u32>``
 adjacency lists guarded by locks.  The TPU-native representation used here is
 a dense slot matrix:
 
-  * ``vectors[n_cap, dim]``  — vector payload per slot
+  * ``vectors[n_cap * nb, 128]`` — vector payload, ``nb = ceil(dim / 128)``
+                               rows of 128 lanes per slot (``row_blocks``),
+                               lanes past ``dim`` zero; read and written only
+                               through ``read_vectors`` / ``write_vectors``
   * ``adj[n_cap, r]``        — out-neighbour ids, ``INVALID`` (-1) padded and
                                kept front-compacted
   * per-slot status masks    — active / tombstone / quarantine
@@ -23,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .quant import QuantStore, init_quant_store
+from .spans import VECTOR_ROWS, device_scope
 
 INVALID = -1
 
@@ -79,6 +83,17 @@ class ANNConfig:
     # the mean in-degree of a degree-R graph is <= R, so 2r covers the
     # bulk of the in-degree distribution).
     local_in_cap: int = 0
+    # The entry point.  False: the first inserted point, replaced when it
+    # is deleted.  True: slot ``ORIGIN``, a frozen point at the zero vector
+    # (FreshDiskANN's frozen start, never deleted), held as a permanent
+    # tombstone: navigable and linkable, never returned or released.  Under
+    # ``ip`` on unit vectors every point is at distance 0 from it and 1 in
+    # the prune's occlusion test (``core/prune.py``), so its row keeps one
+    # neighbour per cluster: a hub into every cluster, where a first point's
+    # row fills with its own cluster once that cluster outgrows r (clusters
+    # of near-equal distances, as in a full-rank 768-d mixture, then never
+    # link to each other).  Policies "ip" and "local"; "fresh" refuses it.
+    origin_start: bool = False
 
     def max_visits(self, l: int) -> int:
         return l + self.max_visit_slack
@@ -115,7 +130,7 @@ class ANNConfig:
 class GraphState(NamedTuple):
     """The full mutable state of one index shard, as a JAX pytree."""
 
-    vectors: jax.Array     # f32[n_cap, dim]
+    vectors: jax.Array     # f32[n_cap * row_blocks(dim), 128]  (see below)
     norms: jax.Array       # f32[n_cap]  squared L2 norms (l2 metric fast path)
     adj: jax.Array         # i32[n_cap, r]  out-neighbours, INVALID padded
     active: jax.Array      # bool[n_cap]  live and returnable
@@ -132,18 +147,84 @@ class GraphState(NamedTuple):
     quant: Optional[QuantStore] = None
 
 
+# ---------------------------------------------------------------------------
+# The vector table: 128-lane rows
+# ---------------------------------------------------------------------------
+#
+# Vector ``s`` is rows ``s * nb ... s * nb + nb - 1`` of a table of 128-lane
+# rows, ``nb = ceil(dim / 128)``, with the lanes past ``dim`` zero.  At dim
+# 128 that is the plain ``f32[n_cap, 128]``.  Under the TPU's (8, 128) tiling
+# a plain ``f32[n_cap, 768]`` row is no legal DMA for the gather kernel, and
+# a ``f32[n_cap, nb, 128]`` table is laid out so that XLA copies all of it
+# before the kernel; in this layout one vector is one ``(nb, 128)`` DMA.
+# Every module reads and writes vectors through the two helpers below, which
+# touch only the rows of the slots they are given: reshaping the whole table
+# between ``(n_cap * nb, 128)`` and ``(n_cap, dim)`` is a relayout copy of
+# all of it on TPU.
+
+ROW_LANES = 128  # lanes of one table row: one TPU lane tile
+
+
+def row_blocks(dim: int) -> int:
+    """Table rows per vector: ``ceil(dim / ROW_LANES)``."""
+    return -(-dim // ROW_LANES)
+
+
+def init_vectors(n_cap: int, dim: int, dtype=jnp.float32) -> jax.Array:
+    """An all-zero table of ``n_cap`` vectors."""
+    return jnp.zeros((n_cap * row_blocks(dim), ROW_LANES), dtype)
+
+
+def read_vectors(vectors: jax.Array, ids: jax.Array, dim: int) -> jax.Array:
+    """The vectors of slots ``ids`` (any shape, each in range), as
+    ``ids.shape + (dim,)``: one row gather of ``nb`` rows per id."""
+    nb = row_blocks(dim)
+    ids = jnp.asarray(ids)
+    with device_scope(VECTOR_ROWS):
+        if nb == 1:
+            rows = vectors[ids]
+        else:
+            rows = vectors[ids[..., None] * nb + jnp.arange(nb)]
+            rows = rows.reshape(ids.shape + (nb * ROW_LANES,))
+        return rows if rows.shape[-1] == dim else rows[..., :dim]
+
+
+def write_vectors(vectors: jax.Array, slots: jax.Array, xs: jax.Array,
+                  dim: int, mode: Optional[str] = None) -> jax.Array:
+    """``vectors`` with ``xs`` (``slots.shape + (dim,)``) written at slots
+    ``slots``, the lanes past ``dim`` zero.  ``mode`` as in ``.at[].set``:
+    with ``"drop"`` a slot past the table's end writes nothing."""
+    nb = row_blocks(dim)
+    slots = jnp.asarray(slots)
+    xs = xs.astype(vectors.dtype)
+    if nb * ROW_LANES != dim:
+        pad = [(0, 0)] * (xs.ndim - 1) + [(0, nb * ROW_LANES - dim)]
+        xs = jnp.pad(xs, pad)
+    if nb == 1:
+        return vectors.at[slots].set(xs, mode=mode)
+    rows = slots[..., None] * nb + jnp.arange(nb)
+    return vectors.at[rows].set(xs.reshape(slots.shape + (nb, ROW_LANES)),
+                                mode=mode)
+
+
+ORIGIN = 0  # the frozen entry point's slot under ``cfg.origin_start``
+
+
 def init_state(cfg: ANNConfig, dtype=jnp.float32) -> GraphState:
     n = cfg.n_cap
+    origin = cfg.origin_start
     return GraphState(
-        vectors=jnp.zeros((n, cfg.dim), dtype),
+        vectors=init_vectors(n, cfg.dim, dtype),
         norms=jnp.zeros((n,), jnp.float32),
         adj=jnp.full((n, cfg.r), INVALID, jnp.int32),
         active=jnp.zeros((n,), bool),
-        tombstone=jnp.zeros((n,), bool),
+        tombstone=jnp.zeros((n,), bool).at[ORIGIN].set(origin),
         quarantine=jnp.zeros((n,), bool),
+        # pops take the top entry first, ORIGIN at index n - 1: a frozen
+        # start's free_top of n - 1 leaves it off the stack
         free_stack=jnp.arange(n - 1, -1, -1, dtype=jnp.int32),
-        free_top=jnp.int32(n),
-        start=jnp.int32(INVALID),
+        free_top=jnp.int32(n - 1 if origin else n),
+        start=jnp.int32(ORIGIN if origin else INVALID),
         n_active=jnp.int32(0),
         n_pending=jnp.int32(0),
         quant=init_quant_store(n, cfg.dim) if cfg.quantized else None,
@@ -274,6 +355,15 @@ def init_index_state(
 def navigable(state: GraphState) -> jax.Array:
     """Slots the greedy search may traverse (live or tombstoned)."""
     return state.active | state.tombstone
+
+
+def recorded(state: GraphState, cfg: ANNConfig) -> jax.Array:
+    """Slots a search's visited list records: the returnable (active) ones
+    and, under ``cfg.origin_start``, the frozen start, so that an insert
+    links to it and a delete repairs its row like any in-neighbour's."""
+    if cfg.origin_start:
+        return state.active.at[ORIGIN].set(True)
+    return state.active
 
 
 def row_count(row: jax.Array) -> jax.Array:

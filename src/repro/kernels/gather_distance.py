@@ -14,9 +14,21 @@ chasing; on TPU we express it as:
     L2 norm term:      d = ||q||^2 + ||x||^2 - 2 <x, q>
     so the distance math rides the matmul unit, not elementwise subtract.
 
-VMEM budget: TILE_K * D * 4B  (64 x 128 x 4 = 32 KiB) plus the (1, D) query —
-far below the ~16 MiB/core VMEM of v5e.  D should be padded to a multiple of
-128 lanes for production tables (interpret-mode tests accept any D).
+The DMA rule.  The table is ``f32[N, W]`` with one row per vector, and the
+query is ``W`` wide: the index's table of 128-lane rows at ``dim <= 128``
+(``core/types.py``; lanes past ``dim`` are zero, and the backend pads the
+query with zeros, which add exactly 0 to either metric).  Each id is one
+``(1, W)`` DMA.  On TPU, where XLA tiles an f32 table (8, 128), W must be
+a multiple of 128 lanes (Mosaic refuses a 100-lane row), and a vector of
+several rows has no single-row DMA: Mosaic refuses a one-row slice of a
+``f32[N, 768]`` table (not aligned to the tiling of 8).  Wider vectors
+take XLA's row gather in ``core/backend.py`` instead: a version of this
+kernel with one ``(nb, 128)`` DMA a vector, started and waited in turn,
+ran three times slower than XLA's gather on a v5e at 768-d.
+Interpret-mode tests accept any W.
+
+VMEM budget: TILE_K * W * 4B  (64 x 128 x 4 = 32 KiB) plus the (1, W) query —
+far below the ~16 MiB/core VMEM of v5e.
 """
 from __future__ import annotations
 

@@ -136,6 +136,9 @@ def make_kernel_distance_fn(*, interpret=None):
     """
 
     def distance_fn(state, cfg, q, ids):
+        # the kernel takes one table row a vector (gather_distance.py): the
+        # query is zero-padded to the row's lanes
+        q = jnp.pad(q, (0, state.vectors.shape[1] - q.shape[0]))
         return gather_distances(
             ids, q, state.vectors, state.norms, metric=cfg.metric,
             interpret=interpret,

@@ -6,7 +6,9 @@ import jax.numpy as jnp
 
 
 def gather_distance_ref(ids, query, vectors, *, metric: str = "l2"):
-    """f32[K] distances from query to vectors[ids]; +inf where ids < 0."""
+    """f32[K] distances from query to vectors[ids]; +inf where ids < 0.
+    ``vectors`` is a plain (N, D) table (the kernels' oracle: the kernels
+    take a table of rows, ``kernels/gather_distance.py``)."""
     safe = jnp.clip(ids, 0, vectors.shape[0] - 1)
     rows = vectors[safe]
     prod = rows @ query

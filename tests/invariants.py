@@ -16,7 +16,8 @@ update policy must preserve:
   from live (active | tombstone | quarantine) slots;
 - accounting: ``free_top + #active + #tombstone + #quarantine == n_cap``,
   ``n_active == #active``, ``n_pending == #tombstone + #quarantine``;
-- a navigable entry point whenever the graph is non-empty;
+- a navigable entry point whenever the graph is non-empty; under
+  ``cfg.origin_start`` always slot 0, a tombstone no policy releases;
 - (IndexState only) ``ext2slot`` / ``slot2ext`` mutually inverse on mapped
   entries, and every mapped slot live;
 - (quantized tier) quant leaf shapes in lockstep with the vector store.
@@ -106,8 +107,12 @@ def check_graph_invariants(
         errs.append(f"out-edge(s) into free slot(s) from rows {rows.tolist()}")
     tomb_ok = not consolidated and policy in (None, "fresh")
     quar_ok = not consolidated and policy in (None, "ip")
+    # the frozen start (cfg.origin_start) is a permanent tombstone that
+    # every policy may link to and that n_pending does not count
+    frozen = np.zeros(n_cap, bool)
+    frozen[0] = cfg.origin_start
     if not tomb_ok:
-        into_tomb = valid & tombstone[clipped]
+        into_tomb = valid & (tombstone & ~frozen)[clipped]
         if into_tomb.any():
             rows = np.flatnonzero(into_tomb.any(axis=1))[:8]
             errs.append(
@@ -155,7 +160,7 @@ def check_graph_invariants(
     # -- accounting ----------------------------------------------------------
     if n_active != int(active.sum()):
         errs.append(f"n_active {n_active} != #active {int(active.sum())}")
-    pend = int(tombstone.sum()) + int(quarantine.sum())
+    pend = int((tombstone & ~frozen).sum()) + int(quarantine.sum())
     if n_pending != pend:
         errs.append(f"n_pending {n_pending} != #tombstone+#quarantine {pend}")
     total = free_top + int(live.sum())
@@ -170,8 +175,12 @@ def check_graph_invariants(
             errs.append(f"start {start} invalid with n_active {n_active} > 0")
         elif not live[start]:
             errs.append(f"start {start} points at a free slot")
-    elif pend == 0 and start != INVALID:
+    elif cfg.origin_start and start != 0:
+        errs.append(f"start {start} is not the frozen origin slot 0")
+    elif not cfg.origin_start and pend == 0 and start != INVALID:
         errs.append(f"start {start} != INVALID on an empty graph")
+    if cfg.origin_start and (start != 0 or not tombstone[0] or active[0]):
+        errs.append("the frozen start left slot 0 or its tombstone")
 
     # -- quantized tier ------------------------------------------------------
     if cfg.quantized:

@@ -75,7 +75,12 @@ def robust_prune_oracle(
                     - 2.0 * float(np.dot(x, vv))
                 )
             else:
-                duv = float(-np.dot(cand_vecs_all[u], vv))
+                # ip: the test compares squared euclidean distances,
+                # |u|^2 + |v|^2 + 2 * (ip distance), on both sides
+                x = cand_vecs_all[u]
+                nx = float(np.dot(x, x))
+                duv = nx + float(np.dot(vv, vv)) - 2.0 * float(np.dot(x, vv))
+                du = nx + float(np.dot(p_vec, p_vec)) + 2.0 * du
             if alpha * duv <= du:
                 drop.append(u)
         for u in drop:

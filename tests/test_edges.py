@@ -9,7 +9,7 @@ import pytest
 from oracles import append_edges_oracle
 from repro.core import ANNConfig, init_state
 from repro.core.edges import LANES, append_edges
-from repro.core.types import INVALID
+from repro.core.types import INVALID, write_vectors
 
 N, DIM, R = 192, 16, 8
 
@@ -79,8 +79,11 @@ def test_append_edges_matches_serial_appends(seed, case, fill, metric):
     # existing rows: an edge already present is skipped, not duplicated
     vs, us = np.append(vs, vs[-1]), np.append(us, adj[vs[-1], 0])
     cfg = ANNConfig(dim=DIM, n_cap=N, r=R, metric=metric, alpha=1.2)
-    state = init_state(cfg)._replace(
-        vectors=jnp.asarray(vecs), norms=jnp.asarray((vecs * vecs).sum(1)),
+    state = init_state(cfg)
+    state = state._replace(
+        vectors=write_vectors(state.vectors, jnp.arange(N), jnp.asarray(vecs),
+                              DIM),
+        norms=jnp.asarray((vecs * vecs).sum(1)),
         adj=jnp.asarray(adj), active=jnp.asarray(active),
         tombstone=jnp.asarray(tombstone),
     )

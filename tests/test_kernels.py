@@ -53,6 +53,71 @@ def test_gather_distance_batched_matches_ref(metric, n, d, b, k):
                                       np.asarray(lane_1d))
 
 
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+@pytest.mark.parametrize("d", [100])
+def test_gather_distance_row_table_matches_ref(metric, d):
+    """The kernels over the index's table of 128-lane rows (one zero-padded
+    row a vector, the query zero-padded to the row) equal the oracle over
+    the plain table; INVALID ids come back +inf, at full and short tiles."""
+    from repro.core.types import write_vectors
+
+    rng = np.random.default_rng(d + 1)     # queries apart from the rows
+    n, b = 150, 3
+    vecs = _data(n, d, seed=d)
+    rows = write_vectors(jnp.zeros((n, 128)), jnp.arange(n),
+                         jnp.asarray(vecs), d)
+    vecs = jnp.asarray(vecs)
+    norms = jnp.sum(vecs * vecs, axis=1)
+    qs = jnp.asarray(rng.normal(size=(b, d)).astype(np.float32))
+    qs_rows = jnp.pad(qs, ((0, 0), (0, 128 - d)))
+    for k in (70, 3):
+        ids = jnp.asarray(rng.integers(-1, n, size=(b, k)).astype(np.int32))
+        ids = ids.at[:, 0].set(-1)
+        want = gather_distance_batched_ref(ids, qs, vecs, metric=metric)
+        for nrm in (norms, None):
+            got = ops.gather_distances_batched(ids, qs_rows, rows, nrm,
+                                               metric=metric, interpret=True)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-5, atol=2e-4)
+            assert np.all(np.isinf(np.asarray(got)[:, 0]))
+            for lane in range(b):
+                lane_1d = ops.gather_distances(ids[lane], qs_rows[lane], rows,
+                                               nrm, metric=metric,
+                                               interpret=True)
+                np.testing.assert_array_equal(np.asarray(got[lane]),
+                                              np.asarray(lane_1d))
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+@pytest.mark.parametrize("d", [256, 768])
+def test_pallas_backend_gathers_multi_row_vectors(metric, d):
+    """At several table rows a vector the pallas backend takes the jnp
+    engine's XLA row gather (the kernel takes one row a vector): the same
+    distances, bit for bit, equal to the oracle over the plain table."""
+    from repro.core import ANNConfig, init_state
+    from repro.core.backend import get_backend
+    from repro.core.types import write_vectors
+
+    rng = np.random.default_rng(d)
+    n, b, k = 150, 3, 70
+    cfg = ANNConfig(dim=d, n_cap=n, metric=metric)
+    vecs = jnp.asarray(_data(n, d, seed=d))
+    st = init_state(cfg)
+    st = st._replace(vectors=write_vectors(st.vectors, jnp.arange(n), vecs, d),
+                     norms=jnp.sum(vecs * vecs, axis=1),
+                     active=jnp.ones((n,), bool))
+    qs = jnp.asarray(rng.normal(size=(b, d)).astype(np.float32))
+    ids = jnp.asarray(rng.integers(-1, n, size=(b, k)).astype(np.int32))
+    got = get_backend("pallas").dists_to_ids_batched(st, cfg, qs, ids)
+    jnp_d = get_backend("jnp").dists_to_ids_batched(st, cfg, qs, ids)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(jnp_d))
+    want = gather_distance_batched_ref(ids, qs, vecs, metric=metric)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-4)
+    one = get_backend("pallas").dists_to_ids(st, cfg, qs[0], ids[0])
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(got[0]))
+
+
 def test_gather_distance_batched_all_invalid():
     vecs = jnp.asarray(_data(32, 8))
     ids = jnp.full((4, 16), -1, jnp.int32)

@@ -8,7 +8,7 @@ from hyp_compat import given, settings, st
 from oracles import robust_prune_oracle
 from repro.core import ANNConfig, init_state, robust_prune
 from repro.core import prune as prune_mod
-from repro.core.types import INVALID
+from repro.core.types import INVALID, write_vectors
 
 
 def _mk_state(cfg, vecs, active=None):
@@ -16,7 +16,8 @@ def _mk_state(cfg, vecs, active=None):
     state = init_state(cfg)
     active = np.ones(n, bool) if active is None else active
     return state._replace(
-        vectors=state.vectors.at[:n].set(jnp.asarray(vecs)),
+        vectors=write_vectors(state.vectors, jnp.arange(n), jnp.asarray(vecs),
+                              cfg.dim),
         norms=state.norms.at[:n].set(jnp.asarray((vecs * vecs).sum(1))),
         active=state.active.at[:n].set(jnp.asarray(active)),
     )
@@ -240,3 +241,29 @@ def test_block_prune_occlusion_product_at_full_precision():
     dots = [ln for ln in text.splitlines() if "stablehlo.dot_general" in ln
             and "tensor<65x128xf32>, tensor<128x65xf32>" in ln]
     assert dots and all("HIGHEST" in ln for ln in dots), dots
+
+
+@pytest.mark.parametrize("c", [65, 192])
+def test_ip_prune_on_unit_vectors_selects_as_l2(c):
+    """A cluster of unit vectors with near-equal products (a full-rank
+    mixture at 768-d): under ``ip`` alpha > 1 must keep more, not prune
+    harder, so the ip prune keeps what the l2 prune keeps on the same unit
+    vectors -- a full row, where a test on negated products keeps one or
+    two -- on both the block (65) and the loop (192) paths."""
+    rng = np.random.default_rng(c)
+    r, dim = 16, 768
+    centre = rng.normal(size=(dim,)).astype(np.float32)
+    pts = centre + 0.35 * rng.normal(size=(c + 1, dim)).astype(np.float32)
+    pts /= np.linalg.norm(pts, axis=1, keepdims=True)
+    vecs, p = pts[:c], pts[c]
+    cand = np.arange(c, dtype=np.int32)
+    rows = {}
+    for metric in ("l2", "ip"):
+        cfg = ANNConfig(dim=dim, n_cap=c, r=r, metric=metric, alpha=1.2)
+        got = np.asarray(robust_prune(_mk_state(cfg, vecs), cfg,
+                                      jnp.asarray(p), jnp.asarray(cand)))
+        rows[metric] = [int(x) for x in got if x >= 0]
+        assert rows[metric] == robust_prune_oracle(
+            metric, 1.2, r, p, cand, vecs, np.ones(c, bool))
+    assert rows["ip"] == rows["l2"]
+    assert len(rows["ip"]) == r

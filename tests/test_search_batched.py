@@ -29,6 +29,7 @@ from repro.core import (
 )
 from repro.core.batched import insert_many_batched
 from repro.core.search_batched import TRACE_COUNTER
+from repro.core.types import read_vectors
 
 BACKENDS = ("jnp", "pallas", "ref")
 DIM = 20  # deliberately not a multiple of 128 (nor of 8)
@@ -253,7 +254,7 @@ def test_masked_lane_never_clobbers_slot_zero():
     assert 0 in slots.tolist()
     for lane, slot in enumerate(slots):
         np.testing.assert_array_equal(
-            np.asarray(state.vectors[slot]), data[lane],
+            np.asarray(read_vectors(state.vectors, slot, DIM)), data[lane],
             err_msg=f"lane {lane} slot {slot} lost its vector",
         )
         np.testing.assert_allclose(

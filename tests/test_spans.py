@@ -1,7 +1,10 @@
 """The program's spans and scopes (core/spans.py).
 
   * the compiled update and search programs carry every device scope in
-    their HLO ``op_name`` metadata;
+    their HLO ``op_name`` metadata (the gather kernel's ``ann.gather`` on
+    the pallas engine, the one that calls it);
+  * the vector-row reads and the gather kernel lie beneath an update phase
+    in the update program, the kernel beneath the hop loop;
   * every operation of the update program that comes from the program's
     own code lies under exactly one phase scope, so a device trace splits
     the program's time among the phases without remainder or overlap;
@@ -9,6 +12,7 @@
     host span and its three children, nested and in order;
   * the retrace counters are one table, shared by the modules that count.
 """
+import dataclasses
 import glob
 import os
 import re
@@ -49,16 +53,23 @@ def _segment(b=4):
     return stack_update_batches([batch]), split
 
 
-def _update_hlo(policy):
+def _update_hlo(policy, cfg=CFG):
     ops, split = _segment()
-    state = init_index_state(CFG, 512)
-    return apply_segment.lower(state, CFG, ops, policy=policy,
+    state = init_index_state(cfg, 512)
+    return apply_segment.lower(state, cfg, ops, policy=policy,
                                split=split).compile().as_text()
 
 
 @pytest.fixture(scope="module")
 def update_hlo():
     return _update_hlo("ip")
+
+
+@pytest.fixture(scope="module")
+def pallas_update_hlo():
+    """The update program on the pallas engine (interpret mode here), whose
+    hop loops call the gather kernel."""
+    return _update_hlo("ip", dataclasses.replace(CFG, backend="pallas"))
 
 
 @pytest.fixture(scope="module")
@@ -131,11 +142,33 @@ def _from_code(op_name, program):
 
 
 @pytest.mark.parametrize("scope", spans.DEVICE_SCOPES)
-def test_compiled_programs_carry_every_scope(update_hlo, search_hlo, scope):
+def test_compiled_programs_carry_every_scope(request, update_hlo, search_hlo,
+                                             scope):
+    if scope == spans.GATHER:
+        update_hlo = request.getfixturevalue("pallas_update_hlo")
     names = {n for _, n in _executed(update_hlo)}
     if scope in (spans.SEARCH_HOPS, spans.SEARCH_SELECT):
         names |= {n for _, n in _executed(search_hlo)}
     assert any(scope in n.split("/") for n in names), scope
+
+
+@pytest.mark.parametrize("scope", [spans.VECTOR_ROWS, spans.GATHER])
+def test_row_and_gather_scopes_nest_in_their_phases(pallas_update_hlo,
+                                                    scope):
+    """Every operation under ``ann.vectors.rows`` or ``ann.gather`` lies
+    beneath exactly one update phase, and the kernel beneath the hop loop
+    of the insert or delete search."""
+    under = [n for _, n in _executed(pallas_update_hlo)
+             if scope in n.split("/")]
+    assert under, scope
+    for n in under:
+        parts = n.split("/")
+        assert len(_phases(n)) == 1, n
+        assert parts.index(_phases(n)[0]) < parts.index(scope), n
+        if scope == spans.GATHER:
+            assert _phases(n)[0] in (spans.INSERT_SEARCH,
+                                     spans.DELETE_SEARCH), n
+            assert spans.SEARCH_HOPS in parts[:parts.index(scope)], n
 
 
 @pytest.mark.parametrize("policy", ["ip", "local", "fresh"])
