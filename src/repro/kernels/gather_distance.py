@@ -18,14 +18,28 @@ The DMA rule.  The table is ``f32[N, W]`` with one row per vector, and the
 query is ``W`` wide: the index's table of 128-lane rows at ``dim <= 128``
 (``core/types.py``; lanes past ``dim`` are zero, and the backend pads the
 query with zeros, which add exactly 0 to either metric).  Each id is one
-``(1, W)`` DMA.  On TPU, where XLA tiles an f32 table (8, 128), W must be
-a multiple of 128 lanes (Mosaic refuses a 100-lane row), and a vector of
-several rows has no single-row DMA: Mosaic refuses a one-row slice of a
-``f32[N, 768]`` table (not aligned to the tiling of 8).  Wider vectors
-take XLA's row gather in ``core/backend.py`` instead: a version of this
-kernel with one ``(nb, 128)`` DMA a vector, started and waited in turn,
-ran three times slower than XLA's gather on a v5e at 768-d.
-Interpret-mode tests accept any W.
+``(1, W)`` DMA, and only ids ``>= 0`` are fetched: the hop loop marks every
+neighbour it does not compare (seen, not navigable, or in a finished lane)
+``INVALID``, so most of a hop's tile needs no row.  ``_fetch_rows`` starts
+a tile's copies back to back, all before the first wait, then waits once
+per started copy on the one DMA semaphore with a descriptor of the same
+``(1, W)`` size, so each wait takes one row's bytes and the tile pays about
+one HBM round trip, not one a row.  The scratch rows of skipped ids keep
+stale data (an earlier tile's rows, or whatever VMEM held); every output
+row depends on its own scratch row only, and the wrappers mask the
+positions of ``INVALID`` ids to ``+inf``, so nothing stale, NaN included,
+reaches a result.  The rows read are then exactly the comparisons the hop
+loop counts in ``n_comps``, so a roofline built on ``n_comps`` is this
+kernel's achieved share of HBM bandwidth.
+
+On TPU, where XLA tiles an f32 table (8, 128), W must be a multiple of 128
+lanes (Mosaic refuses a 100-lane row), and a vector of several rows has no
+single-row DMA: Mosaic refuses a one-row slice of a ``f32[N, 768]`` table
+(not aligned to the tiling of 8).  Wider vectors take XLA's row gather in
+``core/backend.py`` instead: a version of this kernel with one
+``(nb, 128)`` DMA a vector, started and waited in turn, ran three times
+slower than XLA's gather on a v5e at 768-d.  Interpret-mode tests accept
+any W.
 
 VMEM budget: TILE_K * W * 4B  (64 x 128 x 4 = 32 KiB) plus the (1, W) query —
 far below the ~16 MiB/core VMEM of v5e.
@@ -43,20 +57,40 @@ from jax.experimental.pallas import tpu as pltpu
 _ANY = pltpu.MemorySpace.ANY
 
 
+def _fetch_rows(ids_ref, base, tile_k: int, vec_ref, x_scratch, sem):
+    """DMA the table rows of ``ids_ref[base : base + tile_k]`` that are
+    ``>= 0`` into the same rows of ``x_scratch``: every copy started before
+    any is waited on, then one wait per started copy.  Rows of ``INVALID``
+    ids are neither fetched nor cleared (the wrappers mask them)."""
+
+    def row_copy(idx, j):
+        return pltpu.make_async_copy(
+            vec_ref.at[pl.ds(idx, 1), :], x_scratch.at[pl.ds(j, 1), :], sem
+        )
+
+    def start(j, n_started):
+        idx = ids_ref[base + j]
+
+        @pl.when(idx >= 0)
+        def _():
+            row_copy(idx, j).start()
+
+        return n_started + (idx >= 0).astype(jnp.int32)
+
+    n_started = lax.fori_loop(0, tile_k, start, jnp.int32(0))
+
+    def wait(_, carry):
+        # any descriptor of one row's size: each wait takes one row's bytes
+        row_copy(0, 0).wait()
+        return carry
+
+    lax.fori_loop(0, n_started, wait, 0)
+
+
 def _kernel(metric: str, has_norms: bool, tile_k: int, d: int,
             ids_ref, q_ref, n_ref, vec_ref, out_ref, x_scratch, sem):
     i = pl.program_id(0)
-
-    def load_row(j, _):
-        idx = jnp.maximum(ids_ref[i * tile_k + j], 0)
-        cp = pltpu.make_async_copy(
-            vec_ref.at[pl.ds(idx, 1), :], x_scratch.at[pl.ds(j, 1), :], sem
-        )
-        cp.start()
-        cp.wait()
-        return 0
-
-    lax.fori_loop(0, tile_k, load_row, 0)
+    _fetch_rows(ids_ref, i * tile_k, tile_k, vec_ref, x_scratch, sem)
     x = x_scratch[...]                                    # (TILE_K, D)
     q = q_ref[0, :]                                       # (D,)
     prod = jnp.dot(x, q, preferred_element_type=jnp.float32)
@@ -126,17 +160,8 @@ def _kernel_batched(metric: str, has_norms: bool, tile_k: int, kp: int,
                     x_scratch, sem):
     b = pl.program_id(0)
     i = pl.program_id(1)
-
-    def load_row(j, _):
-        idx = jnp.maximum(ids_ref[b * kp + i * tile_k + j], 0)
-        cp = pltpu.make_async_copy(
-            vec_ref.at[pl.ds(idx, 1), :], x_scratch.at[pl.ds(j, 1), :], sem
-        )
-        cp.start()
-        cp.wait()
-        return 0
-
-    lax.fori_loop(0, tile_k, load_row, 0)
+    _fetch_rows(ids_ref, b * kp + i * tile_k, tile_k, vec_ref, x_scratch,
+                sem)
     x = x_scratch[...]                                    # (TILE_K, D)
     q = q_ref[0, :]                                       # (D,)
     prod = jnp.dot(x, q, preferred_element_type=jnp.float32)

@@ -18,6 +18,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import dataclasses
+import itertools
 import re
 
 import pytest
@@ -98,11 +99,14 @@ def test_gather_distance_compiles(one_chip, k, dim, n_cap, metric):
 
 
 @pytest.mark.parametrize("dim,n_cap", KERNEL_WIDTHS, ids=KERNEL_WIDTH_IDS)
-@pytest.mark.parametrize("b", [64, 1024])
+@pytest.mark.parametrize("b", [32, 64, 128, 1024])
 def test_gather_distance_batched_compiles(one_chip, b, dim, n_cap):
-    for k in (64, 1):     # a hop's neighbour tile; the entry point
+    # B 32: a query batch; B 128: the update programs' lanes.  K 64 and 32:
+    # a hop's neighbour tile at R 64 and R 32; K 1: the entry point
+    for metric, k in itertools.product(("l2", "ip"), (64, 32, 1)):
         compiled = _compile(
             lambda i, q, v, n: gather_distance_batched(i, q, v, n,
+                                                       metric=metric,
                                                        interpret=False),
             _spec(one_chip, (b, k), jnp.int32), _spec(one_chip, (b, 128)),
             _spec(one_chip, _table(dim, n_cap)), _spec(one_chip, (n_cap,)),

@@ -133,6 +133,56 @@ def test_gather_distance_all_invalid():
     assert np.all(np.isinf(np.asarray(got)))
 
 
+def _sparse_ids(case, rng, n, b):
+    """(B, K) id tiles of the shapes a hop hands the kernel (tile_k 64)."""
+    k = {"k96": 96, "k50": 50}.get(case, 64)
+    ids = rng.integers(1, n, size=(b, k))
+    if case in ("hop_mask", "nan_row0", "k96", "k50"):
+        # about 85% INVALID: seen, not navigable or in a finished lane
+        ids = np.where(rng.random((b, k)) < 0.85, -1, ids)
+    elif case == "dead_lane":
+        ids[1] = -1
+    elif case == "last_only":
+        ids[:, :-1] = -1
+    elif case == "repeats":
+        ids = np.where(rng.random((b, k)) < 0.3, -1,
+                       rng.choice(ids[0, :3], size=(b, k)))
+    return jnp.asarray(ids.astype(np.int32))
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+@pytest.mark.parametrize("case", ["hop_mask", "dead_lane", "last_only",
+                                  "repeats", "k96", "k50", "nan_row0"])
+def test_gather_distance_sparse_tiles(metric, case):
+    """Tiles of mostly INVALID ids, as a hop sees them: the kernels fetch
+    only the ids >= 0, and the batched and the 1-D kernel equal the oracle
+    and each other per lane, bit for bit.  A NaN row 0 never reaches an
+    INVALID position: those read exactly +inf."""
+    rng = np.random.default_rng(16)
+    n, d, b = 150, 128, 4
+    vecs = _data(n, d, seed=5)
+    if case == "nan_row0":
+        vecs[0] = np.nan
+    vecs = jnp.asarray(vecs)
+    norms = jnp.sum(vecs * vecs, axis=1)
+    qs = jnp.asarray(rng.normal(size=(b, d)).astype(np.float32))
+    ids = _sparse_ids(case, rng, n, b)
+    invalid = np.asarray(ids) < 0
+    want = gather_distance_batched_ref(ids, qs, vecs, metric=metric)
+    for nrm in (norms, None):
+        got = ops.gather_distances_batched(ids, qs, vecs, nrm, metric=metric,
+                                           interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-4)
+        assert not np.any(np.isnan(np.asarray(got)))
+        assert np.all(np.asarray(got)[invalid] == np.inf)
+        for lane in range(b):
+            lane_1d = ops.gather_distances(ids[lane], qs[lane], vecs, nrm,
+                                           metric=metric, interpret=True)
+            np.testing.assert_array_equal(np.asarray(got[lane]),
+                                          np.asarray(lane_1d))
+
+
 @settings(max_examples=12, deadline=None)
 @given(
     n=st.integers(8, 96),
